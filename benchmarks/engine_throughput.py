@@ -675,6 +675,40 @@ def run(quick: bool = False) -> list[str]:
             f";columnar_vs_object={rec['speedup']:.2f}",
         )
     )
+    # The multi-worker row forks its worker processes before any row below
+    # initializes jax: a forked child must never inherit a live backend (on
+    # a TPU host the parent holds the chip).
+    ing = measure_push_source_ingest(
+        batch=2048 if quick else 4096, pushes=40 if quick else 60
+    )
+    rows.append(
+        csv_row(
+            "engine_throughput/push_source_ingest",
+            ing["us_per_push"],
+            f"tuples_per_sec={ing['typed']:.0f}"
+            f";boxed_tuples_per_sec={ing['boxed']:.0f}"
+            f";typed_vs_boxed={ing['speedup']:.2f}",
+        )
+    )
+    mw = measure_multiworker(
+        batch=2048 if quick else 4096, ticks=8 if quick else 12
+    )
+    rows.append(
+        csv_row(
+            "engine_throughput/multiworker",
+            mw["us_per_tick"],
+            f"single_tuples_per_sec={mw['single']:.0f}"
+            f";w2_tuples_per_sec={mw['w2']:.0f}"
+            f";w4_tuples_per_sec={mw['w4']:.0f}"
+            f";w2_vs_single={mw['w2_vs_single']:.2f}"
+            f";w4_vs_single={mw['w4_vs_single']:.2f}"
+            f";xchg_us_per_tick={mw['xchg_us_per_tick']:.1f}"
+            f";xchg_queue_us_per_tick={mw['xchg_queue_us_per_tick']:.1f}"
+            f";xchg_speedup={mw['xchg_speedup']:.2f}"
+            f";xchg_kb_per_tick={mw['xchg_kb_per_tick']:.1f}"
+            f";spread={mw['spread']:.2f}",
+        )
+    )
     jit_batch = 4096 if quick else 8192
     jit_ticks = 10 if quick else 20
     jrec = measure_record_pipeline_jit(batch=jit_batch, ticks=jit_ticks)
@@ -709,37 +743,6 @@ def run(quick: bool = False) -> list[str]:
             f"numpy_us={rs['numpy']:.1f}"
             f";vs_numpy={rs['vs_numpy']:.2f}"
             f";spread={rs['spread']:.2f}",
-        )
-    )
-    ing = measure_push_source_ingest(
-        batch=2048 if quick else 4096, pushes=40 if quick else 60
-    )
-    rows.append(
-        csv_row(
-            "engine_throughput/push_source_ingest",
-            ing["us_per_push"],
-            f"tuples_per_sec={ing['typed']:.0f}"
-            f";boxed_tuples_per_sec={ing['boxed']:.0f}"
-            f";typed_vs_boxed={ing['speedup']:.2f}",
-        )
-    )
-    mw = measure_multiworker(
-        batch=2048 if quick else 4096, ticks=8 if quick else 12
-    )
-    rows.append(
-        csv_row(
-            "engine_throughput/multiworker",
-            mw["us_per_tick"],
-            f"single_tuples_per_sec={mw['single']:.0f}"
-            f";w2_tuples_per_sec={mw['w2']:.0f}"
-            f";w4_tuples_per_sec={mw['w4']:.0f}"
-            f";w2_vs_single={mw['w2_vs_single']:.2f}"
-            f";w4_vs_single={mw['w4_vs_single']:.2f}"
-            f";xchg_us_per_tick={mw['xchg_us_per_tick']:.1f}"
-            f";xchg_queue_us_per_tick={mw['xchg_queue_us_per_tick']:.1f}"
-            f";xchg_speedup={mw['xchg_speedup']:.2f}"
-            f";xchg_kb_per_tick={mw['xchg_kb_per_tick']:.1f}"
-            f";spread={mw['spread']:.2f}",
         )
     )
     assembly, solve, status = measure_milp_assembly(time_limit=0.5 if quick else 1.0)

@@ -14,9 +14,14 @@ import subprocess
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
 
+# fault_recovery forks worker processes: it runs before any module that
+# initializes jax in this process (a forked child must not inherit a live
+# backend; on a TPU host the parent holds the chip).
 MODULES = [
     "solver_perf",          # Figs 2–4
+    "fault_recovery",       # MTTR + tuple loss/duplication under faults
     "engine_throughput",    # data-plane tuples/sec + MILP assembly time
     "integrated_scaling",   # Fig 5
     "milp_vs_flux_potc",    # Figs 6–7
@@ -24,7 +29,6 @@ MODULES = [
     "albic_vs_cola",        # Figs 10–11
     "real_jobs",            # Figs 12–14
     "skew_grid",            # skew scenarios × mitigation strategies
-    "fault_recovery",       # MTTR + tuple loss/duplication under faults
     "roofline_bench",       # dry-run roofline table (this build)
 ]
 
@@ -56,6 +60,7 @@ def main() -> None:
     ap.add_argument("--only", default=None, help="comma-separated module names")
     ap.add_argument("--json", default=None, help="also write rows as JSON to PATH")
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = args.only.split(",") if args.only else MODULES
     print("name,us_per_call,derived")

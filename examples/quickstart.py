@@ -9,6 +9,7 @@ ALBIC, Algorithm 1) rebalance and collocate it live.
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import AdaptationFramework, AlbicParams
 from repro.engine import Controller, ControllerConfig, Engine, ExecutionConfig
 from repro.engine.topology import OperatorSpec, Topology
@@ -32,6 +33,7 @@ def count(state, keys, values, ts):
 
 
 def main() -> None:
+    enable_compile_cache()
     topo = Topology()
     topo.add_operator(OperatorSpec("lines", None, num_keygroups=16, is_source=True))
     topo.add_operator(OperatorSpec("tokenize", tokenize, num_keygroups=16))

@@ -7,10 +7,12 @@ the worker pool elastically.
 
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.serve import main as serve_main
 
 
 def main() -> None:
+    enable_compile_cache()
     extra = sys.argv[1:]
     sys.argv = [
         "serve",

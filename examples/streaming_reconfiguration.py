@@ -10,6 +10,7 @@ load distance low with ≤10 migrations per period.
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import AdaptationFramework, AlbicParams
 from repro.data import airline_stream, real_job_2
 from repro.data.synthetic import StreamSpec
@@ -17,6 +18,7 @@ from repro.engine import Controller, ControllerConfig, Engine, ExecutionConfig
 
 
 def main() -> None:
+    enable_compile_cache()
     nodes, kgs = 6, 30
     topo = real_job_2(keygroups_per_op=kgs)
     g = topo.num_keygroups

@@ -11,10 +11,12 @@ Equivalent to:
 
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.train import main as train_main
 
 
 def main() -> None:
+    enable_compile_cache()
     extra = sys.argv[1:]
     sys.argv = [
         "train",

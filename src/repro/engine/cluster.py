@@ -940,8 +940,10 @@ class ClusterEngine:
         self._op_schema = [
             o.schema if config.use_schema else None for o in topology.operators
         ]
+        # Worker shards are forked: they run numpy only and must never
+        # initialize a jax backend (the parent may hold the accelerator).
         self._worker_config = config.replace(
-            num_workers=1, checkpoint=None, supervision=None
+            num_workers=1, checkpoint=None, supervision=None, kernel_stats=False
         )
         self._timeout = timeout
         worker_cfg = self._worker_config

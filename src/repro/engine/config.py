@@ -145,8 +145,9 @@ class ExecutionConfig:
     use_superstep: bool = False
     jit_mesh: Any = None
     jit_mesh_axis: Optional[str] = None
-    # None → auto-detect (Pallas partition kernel only when jax is already
-    # initialized on TPU); see Engine._auto_kernel_stats.
+    # None → the device tiers (use_fn_jit) route through the Pallas
+    # partition kernel exactly when jax's default backend is a TPU, decided
+    # once at engine construction; the numpy tiers never touch jax.
     kernel_stats: Optional[bool] = None
     num_workers: int = 1
     #: Bytes per (sender → receiver) shared-memory exchange lane in the
@@ -197,11 +198,13 @@ class ExecutionConfig:
                 "shm_lane_bytes must be 0 or >= 64 (a ring smaller than one "
                 "record header can never deliver)"
             )
-        if self.num_workers > 1 and (self.use_fn_jit or self.use_superstep):
+        if self.num_workers > 1 and (
+            self.use_fn_jit or self.use_superstep or self.kernel_stats
+        ):
             raise ValueError(
                 "the multi-worker runtime runs the numpy tiers only "
-                "(use_fn_jit/use_superstep are single-process; see "
-                "docs/execution_tiers.md)"
+                "(use_fn_jit/use_superstep/kernel_stats are single-process; "
+                "see docs/execution_tiers.md)"
             )
         if self.split_degree:
             if self.split_degree < 2:

@@ -7,11 +7,11 @@ never as per-tuple Python objects:
 
 * routing hashes whole key arrays at once (`Topology.keygroups_of`, the same
   32-bit mix the Pallas ``keygroup_partition`` kernel runs on TPU) and splits
-  a batch into per-key-group runs with one stable argsort — on TPU (or with
-  ``kernel_stats=True``) the kernel computes the key-group ids *and* the
-  per-key-group tuple histogram in one pass, and that histogram feeds SPL
-  statistics directly; the numpy path (``np.bincount``) is the bit-identical
-  CPU fallback;
+  a batch into per-key-group runs with one stable argsort — on the device
+  tiers on a TPU (or with ``kernel_stats=True``) the kernel computes the
+  key-group ids *and* the per-key-group tuple histogram in one pass, and
+  that histogram feeds SPL statistics directly; elsewhere the bit-identical
+  numpy path (``np.bincount``) does;
 * work queues are structure-of-arrays (:mod:`repro.engine.workqueue`): a
   routed batch is sorted once by the (destination node, key group)
   composite and pushed as one *segment* per node — a contiguous slice of
@@ -111,7 +111,6 @@ differential conformance harness in ``tests/conformance.py`` — see
 from __future__ import annotations
 
 import dataclasses
-import sys
 import warnings
 from typing import Optional
 
@@ -161,6 +160,10 @@ class EngineMetrics:
     # run_supersteps(K) scan — the metric that proves the superstep path's
     # O(1) crossings per K ticks against the per-operator tier's O(ops·K).
     jit_host_syncs: int = 0
+    # Ticks executed as fused device programs: one per fused superstep tick
+    # that reached the device, K per run_supersteps(K) scan (0 whenever the
+    # superstep runtime fell back to the classic tick).
+    fused_ticks: int = 0
     # Materialized sink tuples; only populated when the engine was built with
     # ``collect_sinks=True`` (unbounded growth otherwise — benchmarks disable
     # it so they measure the data plane, not list appends).
@@ -223,21 +226,6 @@ def _as_batch(outputs) -> Optional[Batch]:
         return None
     keys, values, ts = zip(*outputs)
     return make_batch(keys, values, ts)
-
-
-def _auto_kernel_stats() -> bool:
-    """Use the Pallas partition kernel only when jax is already up on TPU.
-
-    Checked without importing jax: an engine on a CPU host must not pay jax
-    initialization for a path it will never take.
-    """
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 _UNSET = object()  # legacy-kwarg sentinel: distinguishes "not passed"
@@ -352,9 +340,6 @@ class Engine:
         self.latency = LatencyTracker()
         self.backpressure = CreditController(num_nodes, high_wm=50 * service_rate)
         self.collect_sinks = collect_sinks
-        self.kernel_stats = (
-            _auto_kernel_stats() if kernel_stats is None else bool(kernel_stats)
-        )
         self._partition_kernel = None  # lazily imported when kernel_stats is on
         if queue_impl not in QUEUE_IMPLS:
             raise ValueError(f"unknown queue_impl {queue_impl!r}")
@@ -421,8 +406,17 @@ class Engine:
             from repro.kernels.radix_sort import bucket_argsort
 
             self._bucket_argsort = bucket_argsort
+            if kernel_stats is None:
+                # The device tiers route through the Pallas partition kernel
+                # exactly when jax runs on a TPU — decided once, here; a
+                # backend that fails to initialize raises.
+                import jax
+
+                kernel_stats = jax.default_backend() == "tpu"
         else:
             self._bucket_argsort = None
+        # The numpy tiers never touch jax unless kernel_stats=True asks.
+        self.kernel_stats = bool(kernel_stats)
         # superstep=True fuses whole ticks of an eligible linear fn_jit
         # chain into single device programs (repro.engine.superstep); the
         # runtime falls back to the classic tick whenever a tick is not

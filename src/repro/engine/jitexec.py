@@ -33,12 +33,15 @@ number) — so σ_k pickles, ``kg_state_bytes`` and the conformance state
 comparison see exactly the dict the per-run oracle would have produced.
 
 Float-tolerance policy: integer columns, single float operations and the
-first addend of every running sum are bit-exact; *multi-term float
-reductions* (``jnp.cumsum`` inside :func:`keyed_running_sum`) may diverge
-from the oracle's strict left-to-right association in the last bits — the
-conformance harness compares the jit configuration with a documented
-``rtol=1e-9`` on floats for exactly this reason (see tests/conformance.py
-and docs/operator_authoring.md).
+first two addends of every running sum are bit-exact on the CPU;
+*multi-term float reductions* (the segmented scan inside
+:func:`keyed_running_sum`) may diverge from the oracle's strict
+left-to-right association in the last bits — the conformance harness
+compares the jit configuration with a documented ``rtol=1e-9`` on floats
+for exactly this reason (see tests/conformance.py and
+docs/operator_authoring.md).  On a TPU float64 is emulated: no float
+operation is bit-exact there and the exponent range is float32's, so every
+float is held to the tolerance (``chip_smoke.py``); integers stay exact.
 """
 
 from __future__ import annotations
@@ -63,6 +66,7 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp  # noqa: E402  (after the x64 flag, deliberately)
 
 from repro.engine.topology import StateField, Topology  # noqa: E402
+from repro.kernels.radix_sort import radix_argsort_jax  # noqa: E402
 
 # Sentinel for unused table slots and padding tuple codes.  Real codes must
 # be < EMPTY_CODE (any keyed state built from finite attributes is).
@@ -155,6 +159,25 @@ def count_runs(col: jax.Array, kgs, starts, ends) -> jax.Array:
     )
 
 
+def segmented_prefix_sum(x: jax.Array, head: jax.Array) -> jax.Array:
+    """Inclusive prefix sums of ``x`` that restart at every ``head``.
+
+    A log-depth parallel scan: ``jnp.cumsum`` lowers to a window reduction
+    that XLA:TPU compiles for minutes on emulated float64, this compiles in
+    seconds for every dtype.  Each sum takes only its own segment's terms,
+    so float error scales with the segment's magnitude, not the batch's;
+    the association differs from a left-to-right fold only in the last
+    bits, and not at all for a segment's first two terms.
+    """
+
+    def combine(a, b):
+        ha, xa = a
+        hb, xb = b
+        return ha | hb, jnp.where(hb, xb, xa + xb)
+
+    return jax.lax.associative_scan(combine, (head, x))[1]
+
+
 def keyed_running_sum(
     table: TableState,
     codes: jax.Array,
@@ -174,14 +197,15 @@ def keyed_running_sum(
     (key the table by the operator's partition key), and real codes are
     non-negative and < 2^63 − 1.
 
-    Cost: ONE stable sort of the segment (the only comparison sort — the
+    Cost: ONE stable sort of the segment (:func:`radix_argsort_jax`; the
     table's code-sorted view is maintained incrementally by merging the
     segment's pre-sorted new codes: searchsorted + prefix sums), plus
-    O(segment + capacity) gathers/scatters.  The within-group prefix is
-    computed via ``jnp.cumsum`` — the one place the jit tier's floats may
-    diverge from the oracle's left-to-right association (module docstring's
-    tolerance policy); group heads take ``base + addend`` directly, so
-    singleton groups (mostly-unique keys) stay bit-exact end to end.
+    O(segment + capacity) gathers/scatters.  The within-group running sums
+    are one :func:`segmented_prefix_sum` seeded with each group's table
+    value — the one place the jit tier's floats may diverge from the
+    oracle's left-to-right association (module docstring's tolerance
+    policy); a group's first two sums associate exactly as the oracle's,
+    so singleton groups (mostly-unique keys) stay bit-exact end to end.
 
     ``order`` is the *pre-sorted order handoff*: the caller may pass the
     stable argsort of ``where(valid, codes, EMPTY_CODE)`` computed outside
@@ -195,7 +219,7 @@ def keyed_running_sum(
     cap = table.codes.shape[0]
     mcodes = jnp.where(valid, codes, EMPTY_CODE)
     if order is None:
-        order = jnp.argsort(mcodes)  # stable: ties keep original tuple order
+        order = radix_argsort_jax(mcodes)  # stable: ties keep tuple order
     sc = mcodes[order]
     real = sc != EMPTY_CODE
     sk = jnp.where(real, kg[order], 0)
@@ -207,20 +231,16 @@ def keyed_running_sum(
     fidx = table.perm[pos].astype(jnp.int64)  # candidate slab index
     has = (scodes[pos] == sc) & real
     base = jnp.where(has, table.vals[fidx], jnp.zeros((), table.vals.dtype))
-    # Within-group inclusive prefix of the addends.
-    csum = jnp.cumsum(sa)
-    seg = jnp.cumsum(head) - 1  # group index per sorted position
-    gstart = (
-        jnp.zeros(nb, csum.dtype)
-        .at[jnp.where(head, seg, nb)]
-        .set(jnp.where(head, csum - sa, 0), mode="drop")
-    )
-    running_sorted = jnp.where(head, base + sa, base + (csum - gstart[seg]))
+    # Within-group running sums, each group seeded with its table value.
+    running_sorted = segmented_prefix_sum(jnp.where(head, base + sa, sa), head)
     running = jnp.zeros(nb, running_sorted.dtype).at[order].set(running_sorted)
+    # Integer prefix counts run in int32 (they are bounded by the segment
+    # and table sizes): XLA:TPU compiles int64 scans several times slower.
+    seg = jnp.cumsum(head, dtype=jnp.int32) - 1  # group per sorted position
     # ---- table update ----------------------------------------------------
     tail = jnp.concatenate([head[1:], jnp.ones(1, bool)])
     newhead = head & real & ~has
-    nc_in = jnp.cumsum(newhead.astype(jnp.int64))  # inclusive new count
+    nc_in = jnp.cumsum(newhead, dtype=jnp.int32).astype(jnp.int64)  # new count
     total_new = nc_in[-1]
     rank = nc_in - 1  # code-order rank among new codes (valid at newheads)
     dest = table.cnt.astype(jnp.int64) + rank  # slab append position
@@ -248,7 +268,7 @@ def keyed_running_sum(
     ncex = jnp.concatenate([jnp.zeros(1, jnp.int64), nc_in])  # exclusive
     is_empty_old = scodes == EMPTY_CODE
     shift_real = ncex[jnp.searchsorted(sc, scodes, side="left")]
-    jemp = jnp.cumsum(is_empty_old.astype(jnp.int64)) - 1
+    jemp = jnp.cumsum(is_empty_old, dtype=jnp.int32) - 1
     arange_cap = jnp.arange(cap)
     oldpos = jnp.where(
         is_empty_old,
@@ -292,19 +312,6 @@ def _jitted_plain(fn):
     if "plain" not in entry:
         entry["plain"] = jax.jit(fn, donate_argnums=0)
     return entry["plain"]
-
-
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """Version-portable shard_map (mirrors repro.models.moe's shim)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
 
 
 def _jitted_sharded(fn, mesh, axis):
@@ -358,9 +365,9 @@ def _jitted_sharded(fn, mesh, axis):
 
                 return state_m, jax.tree_util.tree_map(omerge, outputs)
 
-            state_m, outputs = _shard_map(
+            state_m, outputs = jax.shard_map(
                 shard,
-                mesh,
+                mesh=mesh,
                 in_specs=(P(), P(axis), P(axis), P(axis), P(), P(), P()),
                 out_specs=P(),
             )(state, kgs, starts, ends, keys, values, ts)
@@ -459,9 +466,9 @@ def _jitted_sharded_tables(fn, mesh, axis, table_names):
 
                 return out_state, jax.tree_util.tree_map(omerge, outputs)
 
-            state_m, outputs = _shard_map(
+            state_m, outputs = jax.shard_map(
                 shard,
-                mesh,
+                mesh=mesh,
                 in_specs=(state_spec, P(axis), P(axis), P(axis), P(), P(), P()),
                 out_specs=(state_spec, P()),
             )(state, kgs, starts, ends, keys, values, ts)
@@ -738,7 +745,7 @@ class JitRuntime:
         (stable sort; a key group lives wholly on one shard), and equal
         codes share a key group — so the per-code tie-break order inside
         :func:`keyed_running_sum` is unchanged and the result is
-        bit-identical to the plain call, modulo the documented cumsum
+        bit-identical to the plain call, modulo the documented prefix-sum
         float policy.  Outputs come back positionally over the permuted
         tuples and are ungathered on the host (1:1 bodies only).
         """

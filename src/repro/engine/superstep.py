@@ -144,7 +144,7 @@ class _Plan:
 def plan_chain(engine) -> Optional[_Plan]:
     """Static superstep eligibility; ``None`` → this engine never fuses."""
     topo = engine.topology
-    if engine.kernel_stats or engine._jit_mesh is not None:
+    if engine._jit_mesh is not None:
         return None
     if not engine.use_schema:
         return None
@@ -598,6 +598,7 @@ class SuperstepRuntime:
             metrics.jit_calls += 1
             metrics.jit_tuples += n_by_i[i]
         metrics.jit_host_syncs += 1
+        metrics.fused_ticks += 1
 
         # -- emission accounting + sink download (mirrors _flush_jit_batch) --
         for i in active:
@@ -1022,6 +1023,7 @@ class SuperstepRuntime:
         metrics.ticks += K
         eng._ticks_this_period += K
         metrics.jit_host_syncs += 1
+        metrics.fused_ticks += K
         metrics.jit_calls += K * len(fops)
         last = len(fops) - 1
         for i, op in enumerate(fops):
@@ -1227,8 +1229,10 @@ class SuperstepRuntime:
                 else:
                     keys, values, ts, counts = pends[i - 1]
                 perm = perms_dev[i]
-                cp = counts[perm]
-                e_run = jnp.cumsum(cp)
+                # int32 (run ends are bounded by the batch): XLA:TPU's int64
+                # window scan inside the scan body overruns scoped VMEM.
+                cp = counts[perm].astype(jnp.int32)
+                e_run = jnp.cumsum(cp, dtype=jnp.int32)
                 s_run = e_run - cp
                 st, out, oc = fns[i](
                     states[i], perm, s_run, e_run, keys, values, ts
@@ -1326,12 +1330,6 @@ def _emits(spec) -> bool:
         )
         return out is not None
 
-    try:
-        emits = probe()
-    except Exception:
-        emits = True
-    try:
-        spec._superstep_emits = emits
-    except Exception:
-        pass
+    emits = probe()
+    spec._superstep_emits = emits
     return emits

@@ -1,4 +1,9 @@
-"""Dispatching wrapper for the hash-partition/histogram kernel."""
+"""Dispatching wrapper for the hash-partition/histogram kernel.
+
+On TPU (or with ``force_pallas``) the Pallas kernel runs — compiled on the
+chip, interpreted only when forced on another backend; elsewhere the jnp
+oracle does.  Neither path falls back to the other.
+"""
 
 from __future__ import annotations
 
@@ -10,6 +15,8 @@ from repro.kernels.keygroup_partition.keygroup_partition import (
     keygroup_partition_pallas,
 )
 from repro.kernels.keygroup_partition.ref import keygroup_partition_ref
+
+_MIN_PAD = 1024
 
 
 def fold_keys64(keys: np.ndarray) -> np.ndarray:
@@ -38,13 +45,29 @@ def keygroup_partition(
     """
     if len(np.asarray(keys)) == 0:
         return np.empty(0, dtype=np.int64), np.zeros(num_keygroups, dtype=np.int64)
-    folded = jnp.asarray(fold_keys64(keys))
+    folded = fold_keys64(keys)
+    n = len(folded)
     on_tpu = jax.default_backend() == "tpu"
     if on_tpu or force_pallas:
-        valid = jnp.ones(folded.shape[0], jnp.int32)
+        if not 0 < num_keygroups < 2**31:
+            raise ValueError(
+                f"keygroup_partition serves 1 <= num_keygroups < 2**31, "
+                f"got {num_keygroups}"
+            )
+        # Power-of-two padding (valid=0, so the histogram ignores it): a
+        # stream of varied batch sizes compiles a handful of programs.
+        npad = max(_MIN_PAD, 1 << (n - 1).bit_length())
+        keys32 = np.zeros(npad, dtype=np.int32)
+        keys32[:n] = folded
+        valid = np.zeros(npad, dtype=np.int32)
+        valid[:n] = 1
         kg, hist = keygroup_partition_pallas(
-            folded, valid, num_keygroups=num_keygroups, interpret=not on_tpu
+            jnp.asarray(keys32),
+            jnp.asarray(valid),
+            num_keygroups=num_keygroups,
+            interpret=not on_tpu,
         )
+        kg = np.asarray(kg)[:n]
     else:
-        kg, hist = keygroup_partition_ref(folded, num_keygroups)
+        kg, hist = keygroup_partition_ref(jnp.asarray(folded), num_keygroups)
     return np.asarray(kg, dtype=np.int64) + base, np.asarray(hist, dtype=np.int64)

@@ -9,6 +9,7 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -23,7 +24,11 @@ def make_production_mesh(*, multi_pod: bool = False):
             "set XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import (see launch/dryrun.py)"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    # Auto axes: the model code places activations with
+    # with_sharding_constraint, which explicit-axis meshes reject.
+    return jax.make_mesh(
+        shape, axes, devices=devices[:need], axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -32,4 +37,9 @@ def make_host_mesh(data: int = 1, model: int = 1):
     devices = jax.devices()
     if len(devices) < n:
         raise RuntimeError(f"need {n} devices, have {len(devices)}")
-    return jax.make_mesh((data, model), ("data", "model"), devices=devices[:n])
+    return jax.make_mesh(
+        (data, model),
+        ("data", "model"),
+        devices=devices[:n],
+        axis_types=(AxisType.Auto,) * 2,
+    )

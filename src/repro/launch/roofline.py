@@ -31,8 +31,9 @@ layer count.  Instead:
 
 ``cost_analysis`` numbers are still recorded (``xla_*``) for reference.
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (one link charged per chip: conservative).
+Hardware constants come from :data:`PEAKS`, keyed by the chip's
+``device_kind``; a kind not in the table is an error, never a default.
+The dry run models a TPU v5e (``"TPU v5 lite"``) whatever host compiles it.
 """
 
 from __future__ import annotations
@@ -43,9 +44,34 @@ from typing import Optional
 
 
 
-PEAK_FLOPS = 197e12  # bf16 per chip
-HBM_BW = 819e9  # bytes/s per chip
-ICI_BW = 50e9  # bytes/s per link (1 link charged per chip)
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # HBM bytes/s per chip
+    ici_bw: float  # bytes/s per inter-chip link (one link charged per chip)
+
+
+#: Published per-chip peaks by ``jax.Device.device_kind``.  TPU v5e: Google
+#: Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM,
+#: 1,600 Gbit/s of inter-chip interconnect (charged here as one ~50 GB/s
+#: link per chip: conservative).
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+#: The chip the dry run models.
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of one chip of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
 
 _DTYPE_BYTES = {
     "pred": 1,
@@ -485,6 +511,7 @@ def build_report(
     compiled,
     hlo_text: Optional[str] = None,
 ) -> RooflineReport:
+    peaks = chip_peaks(DRYRUN_DEVICE_KIND)
     text = hlo_text if hlo_text is not None else compiled.as_text()
     analysis = analyze_hlo(text)
     mem = None
@@ -506,9 +533,9 @@ def build_report(
         hlo_bytes_per_device=analysis.hbm_bytes,
         wire_bytes_per_device=analysis.collectives.total_wire_bytes,
         model_flops=model_flops_estimate(cfg, shape),
-        compute_s=analysis.flops / PEAK_FLOPS,
-        memory_s=analysis.hbm_bytes / HBM_BW,
-        collective_s=analysis.collectives.total_wire_bytes / ICI_BW,
+        compute_s=analysis.flops / peaks.flops,
+        memory_s=analysis.hbm_bytes / peaks.hbm_bw,
+        collective_s=analysis.collectives.total_wire_bytes / peaks.ici_bw,
         collectives=analysis.collectives.counts,
         bytes_per_device_hbm=mem,
     )

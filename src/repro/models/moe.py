@@ -182,21 +182,14 @@ def _moe_expert_parallel(
     weights = (w_gate, p["w_up"], p["w_down"]) if has_gate else (p["w_up"], p["w_down"])
     in_specs = (P(batch_axes, None, None), P(), *([w_spec] * len(weights)))
     out_specs = P(batch_axes, None, None)
-    if hasattr(jax, "shard_map"):
-        mapped = jax.shard_map(
-            body,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            axis_names=set(mesh.axis_names),
-            check_vma=False,
-        )
-    else:  # jax < 0.6: shard_map lives in experimental, check flag named check_rep
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        mapped = _shard_map(
-            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
+    mapped = jax.shard_map(
+        body,
+        mesh=mesh,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        axis_names=set(mesh.axis_names),
+        check_vma=False,
+    )
     return mapped(x, p["router"], *weights)
 
 

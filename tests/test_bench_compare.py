@@ -161,9 +161,9 @@ def test_quick_run_writes_json(tmp_path: Path):
         capture_output=True,
         text=True,
         cwd=str(Path(__file__).parent.parent),
-        # Minimal env, but keep the jax backend selection: dropping
-        # JAX_PLATFORMS on a TPU-credentialed host sends the subprocess
-        # into a multi-minute TPU-init stall before falling back.
+        # Minimal env, pinned to the CPU backend: this checks the harness's
+        # JSON output, not device numbers, and a subprocess must never take
+        # an accelerator its parent may hold.
         env={
             "PYTHONPATH": "src",
             "PATH": "/usr/bin:/bin:/usr/local/bin",

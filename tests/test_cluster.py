@@ -8,7 +8,13 @@ reproducibility, the pipelined ``run_stream`` mode, the public envelope
 API, and the coordinator's elastic/lifecycle surface.
 """
 
+import os
+import subprocess
+import sys
+import textwrap
+
 import numpy as np
+import pytest
 
 from conformance import (
     Scenario,
@@ -25,6 +31,7 @@ from repro.engine.cluster import (
 )
 
 KGS = 8
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _cluster(num_workers=2, num_nodes=4, service_rate=1e9, seed=0, **kw):
@@ -245,6 +252,64 @@ def test_close_terminates_worker_processes():
         p.join(timeout=10)
     assert not any(p.is_alive() for p in procs)
     eng.close()  # idempotent
+
+
+def test_worker_config_pins_numpy_partitioning():
+    with _cluster() as eng:
+        assert eng._worker_config.kernel_stats is False
+    with pytest.raises(ValueError, match="numpy tiers only"):
+        ExecutionConfig.workers(2).replace(kernel_stats=True)
+
+
+SHARDS_NEVER_TOUCH_JAX = textwrap.dedent(
+    """
+    import sys, types
+
+    class _Poisoned(types.ModuleType):
+        def __getattr__(self, name):
+            raise RuntimeError(f"jax.{name} reached from " + str(os.getpid()))
+
+    import os
+    # Any jax use by the coordinator or a forked shard raises.
+    sys.modules["jax"] = _Poisoned("jax")
+    import numpy as np
+    from repro.data import airline_stream, real_job_2
+    from repro.data.synthetic import StreamSpec
+    from repro.engine import ExecutionConfig, make_engine
+
+    # real_job_2 declares fn_jit bodies and integer keys: the shape that
+    # would take the partition kernel on a device tier.
+    with make_engine(real_job_2(keygroups_per_op=8), 4,
+                     config=ExecutionConfig.workers(2),
+                     service_rate=1e9, seed=0, timeout=60.0) as eng:
+        stream = airline_stream(StreamSpec(rate=200.0, seed=3))
+        for _ in range(4):
+            eng.push_source("airline", *next(stream))
+            eng.tick()
+        for _ in range(6):
+            eng.tick()
+        eng.finalize()
+        assert eng.metrics.sink_tuples > 0
+    print("SHARDS-NO-JAX-OK")
+    """
+)
+
+
+def test_forked_shards_never_touch_jax():
+    """Worker shards fork from a parent that may hold the accelerator: with
+    jax poisoned in the parent, a shard (or the coordinator) that reaches
+    for it would raise and the run would fail."""
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    proc = subprocess.run(
+        [sys.executable, "-c", SHARDS_NEVER_TOUCH_JAX],
+        capture_output=True,
+        text=True,
+        timeout=300,
+        env=env,
+        cwd=REPO,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "SHARDS-NO-JAX-OK" in proc.stdout
 
 
 # ---------------------------------------------------------------------------

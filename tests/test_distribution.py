@@ -12,6 +12,7 @@ from repro.configs import SHAPES, get_config
 from repro.launch.roofline import (
     CollectiveStats,
     analyze_hlo,
+    chip_peaks,
     model_flops_estimate,
     parse_collectives,
 )
@@ -117,6 +118,13 @@ def test_analyze_hlo_flops_trip_weighting():
     assert a.hbm_bytes > 0
 
 
+def test_chip_peaks_are_keyed_by_device_kind():
+    v5e = chip_peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(ValueError, match="no published peaks"):
+        chip_peaks("cpu")
+
+
 def test_model_flops_estimates():
     cfg = get_config("glm4_9b")
     train = model_flops_estimate(cfg, SHAPES["train_4k"])
@@ -136,7 +144,7 @@ SMALL_DRYRUN = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, dataclasses
     import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.configs import SHAPES, get_config
     from repro.launch import sharding as shd
     from repro.launch.roofline import analyze_hlo
@@ -144,7 +152,9 @@ SMALL_DRYRUN = textwrap.dedent(
     from repro.models.common import activation_rules
     from repro.optim import AdamW
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh(
+        (2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )
     cfg = get_config("dbrx_132b", smoke=True)
     cfg = dataclasses.replace(cfg, vocab_size=512, cycles=2)
     shape = dataclasses.replace(SHAPES["train_4k"], seq_len=64, global_batch=4)

@@ -240,6 +240,34 @@ def test_kernel_histogram_wiring_matches_numpy_engine():
     assert np.array_equal(s1.out_rates, s2.out_rates)
 
 
+def test_kernel_stats_is_decided_from_the_backend_at_construction(monkeypatch):
+    """kernel_stats=None: the device tiers take the partition kernel exactly
+    when jax's backend is a TPU, whatever was imported before; the numpy
+    tiers never ask; a failing backend query propagates."""
+    import jax
+
+    from repro.data.jobs import real_job_2
+    from repro.engine import Engine, ExecutionConfig
+
+    def build(config):
+        return Engine(real_job_2(keygroups_per_op=4), 2, config=config)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert build(ExecutionConfig.jit()).kernel_stats
+    assert build(ExecutionConfig.superstep()).kernel_stats
+    assert not build(ExecutionConfig.typed()).kernel_stats
+    assert not build(ExecutionConfig.jit().replace(kernel_stats=False)).kernel_stats
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert not build(ExecutionConfig.jit()).kernel_stats
+
+    def broken():
+        raise RuntimeError("backend failed to initialize")
+
+    monkeypatch.setattr(jax, "default_backend", broken)
+    with pytest.raises(RuntimeError, match="backend failed"):
+        build(ExecutionConfig.jit())
+
+
 def test_kernel_histogram_wiring_nonint_keys_fall_back():
     """String keys can't ride the int-mix kernel: the engine silently uses
     the numpy path and the statistics remain correct."""

@@ -117,3 +117,41 @@ def test_property_bit_identical_permutation(n, nb, block, seed):
     np.testing.assert_array_equal(
         np.asarray(out), np.argsort(codes, kind="stable")
     )
+
+
+def test_radix_argsort_tpu_passes_compose_to_a_stable_sort(monkeypatch):
+    """The TPU branch of radix_argsort_jax (8-bit LSD passes of the kernel)
+    run here with the kernel interpreted: int64 codes, the running sums'
+    padding code included, sort exactly as numpy's stable argsort."""
+    import functools
+
+    import jax
+
+    from repro.kernels.radix_sort import ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        ops,
+        "bucket_argsort_pallas",
+        functools.partial(bucket_argsort_pallas, interpret=True),
+    )
+    rng = np.random.default_rng(4)
+    codes = rng.integers(0, 2**62, size=700)
+    codes[::5] = codes[1::5][: len(codes[::5])]  # ties
+    codes[::7] = np.iinfo(np.int64).max
+    x64 = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", True)  # as the jit tier runs it
+    try:
+        out = ops.radix_argsort_jax(jnp.asarray(codes, dtype=jnp.int64))
+    finally:
+        jax.config.update("jax_enable_x64", x64)
+    np.testing.assert_array_equal(
+        np.asarray(out), np.argsort(codes, kind="stable")
+    )
+
+
+@pytest.mark.parametrize("nb", [0, 2**31 - 1])
+def test_dispatchers_refuse_bucket_counts_the_kernel_cannot_serve(nb):
+    codes = np.zeros(10, dtype=np.int64)
+    with pytest.raises(ValueError, match="serves"):
+        bucket_argsort(codes, nb, force_pallas=True)

@@ -152,6 +152,20 @@ def test_fused_tick_is_bit_identical_and_syncs_once_per_tick():
     assert eb.metrics.jit_host_syncs <= eb.metrics.ticks
 
 
+def test_fused_tick_with_kernel_stats_feeds_the_histogram():
+    """With the partition kernel on (the TPU default) the engine still
+    fuses: fused ticks record arrivals from their own per-edge counts, and
+    every statistic matches the kernel-free classic run."""
+    ea = _engine(False)
+    _drive(ea)
+    eb = Engine(make_pipeline_topo(), 4, service_rate=1e9, seed=0,
+                config=ExecutionConfig.superstep().replace(kernel_stats=True))
+    _drive(eb)
+    assert eb.kernel_stats and eb.metrics.fused_ticks > 0
+    assert ea.metrics.fused_ticks == 0
+    assert _result(ea) == _result(eb)
+
+
 def test_migration_blobs_byte_identical_at_superstep_boundary():
     ea = _engine(False)
     blobs_a = _drive(ea, migrate_at=(3, 7), collect_blobs=True)
