@@ -1,0 +1,8 @@
+"""Host time of ``AdaptationFramework.adapt`` (ALBIC, MILP), mean over the
+window's periods that adapted."""
+
+from chipbench.readers import span_ms_mean
+
+
+def read(run):
+    return span_ms_mean(run, "adapt")
