@@ -105,15 +105,19 @@ def execute_plan(
     and buffering keep upstream flowing meanwhile — this is what keeps the
     paper's per-key-group latency at ~2.5 s rather than a full-job stall.
     """
+    # Imported here: the engine package imports this one.
+    from repro.engine.tracing import span
+
     pause = 0.0
     bytes_moved = 0
     for m in plan.moves:
         mover.redirect(m.keygroup, m.dst)
-        t0 = time.perf_counter() if measure else 0.0
-        blob = mover.serialize(m.keygroup)
-        mover.install(m.keygroup, m.dst, blob)
-        if measure:
-            pause += time.perf_counter() - t0
+        with span("migration.pause"):
+            t0 = time.perf_counter() if measure else 0.0
+            blob = mover.serialize(m.keygroup)
+            mover.install(m.keygroup, m.dst, blob)
+            if measure:
+                pause += time.perf_counter() - t0
         bytes_moved += len(blob)
     return MigrationReport(
         applied=len(plan.moves),

@@ -18,6 +18,7 @@ from repro.core.framework import AdaptationFramework, AdaptationResult
 from repro.core.migration import execute_plan
 from repro.core.stats import ClusterState
 from repro.engine.executor import Engine
+from repro.engine.tracing import span
 
 
 @dataclasses.dataclass
@@ -46,6 +47,13 @@ class PeriodMetrics:
     num_unsplits: int = 0
     #: Worker recoveries (supervised respawn + rewind) completed this period.
     num_recoveries: int = 0
+    #: Blocking device→host reads and keyed-table capacity bumps this period
+    #: (EngineMetrics.device_fetches, table_grows), and the keyed device
+    #: tables' entries and slots at its end.
+    device_fetches: int = 0
+    table_grows: int = 0
+    table_entries: int = 0
+    table_capacity: int = 0
 
 
 class Controller:
@@ -75,6 +83,9 @@ class Controller:
     def period(self, *, adapt: bool = True) -> PeriodMetrics:
         """One SPL: execute ticks, snapshot stats, adapt, migrate, record."""
         recoveries_before = len(getattr(self.engine, "recoveries", ()))
+        counters = self.engine.metrics
+        fetches_before = counters.device_fetches
+        grows_before = counters.table_grows
         self.run_ticks(self.config.ticks_per_period)
         snapshot = self.engine.end_period()
 
@@ -83,15 +94,16 @@ class Controller:
         num_splits = num_unsplits = 0
         if adapt and self._period >= self.config.warmup_periods:
             splitting = self.framework.splitter is not None
-            result = self.framework.adapt(
-                snapshot,
-                split_families=(
-                    self.engine.split_families() if splitting else None
-                ),
-                split_eligible=(
-                    self.engine.split_eligible() if splitting else None
-                ),
-            )
+            with span("controller.decide"):
+                result = self.framework.adapt(
+                    snapshot,
+                    split_families=(
+                        self.engine.split_families() if splitting else None
+                    ),
+                    split_eligible=(
+                        self.engine.split_eligible() if splitting else None
+                    ),
+                )
             # Elastic scaling against the engine.
             if result.scaling.add_nodes:
                 self.engine.add_nodes(result.scaling.add_nodes)
@@ -151,6 +163,10 @@ class Controller:
             num_recoveries=(
                 len(getattr(self.engine, "recoveries", ())) - recoveries_before
             ),
+            device_fetches=counters.device_fetches - fetches_before,
+            table_grows=counters.table_grows - grows_before,
+            table_entries=counters.table_entries,
+            table_capacity=counters.table_capacity,
         )
         self.engine.latency.reset()
         self.history.append(metrics)

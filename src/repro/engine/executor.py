@@ -129,6 +129,7 @@ from repro.engine.topology import (
     _identity_key,
     make_batch,
 )
+from repro.engine.tracing import enable as enable_spans, span, traced
 from repro.engine.workqueue import _S_CUR, QUEUE_IMPLS, SoAWorkQueue
 
 
@@ -155,11 +156,22 @@ class EngineMetrics:
     jit_calls: int = 0
     jit_tuples: int = 0
     jit_compiles: int = 0
-    # Host↔device boundary crossings of the compiled tier: one per
-    # per-operator jit call, one per fused superstep tick, one per
-    # run_supersteps(K) scan — the metric that proves the superstep path's
-    # O(1) crossings per K ticks against the per-operator tier's O(ops·K).
+    # Launches of the compiled tier: one per per-operator jit call, one per
+    # fused superstep tick, one per run_supersteps(K) scan — the metric that
+    # proves the superstep path's O(1) launches per K ticks against the
+    # per-operator tier's O(ops·K).  It counts calls, not reads: a call
+    # fetches several result arrays (device_fetches).
     jit_host_syncs: int = 0
+    # Blocking device→host reads on the jit tier's tick and end_period
+    # paths, counted where each happens: jit results, keyed-table reads
+    # (capacity bumps, dict pushes, state materialisation) and the routing
+    # kernels' results.
+    device_fetches: int = 0
+    # Keyed device tables summed over every table: entries held and slots
+    # allocated (gauges, from the host's own counts), and capacity bumps.
+    table_entries: int = 0
+    table_capacity: int = 0
+    table_grows: int = 0
     # Ticks executed as fused device programs: one per fused superstep tick
     # that reached the device, K per run_supersteps(K) scan (0 whenever the
     # superstep runtime fell back to the classic tick).
@@ -176,9 +188,6 @@ class EngineMetrics:
     # gauge for the same traffic.
     hot_keygroups: list = dataclasses.field(default_factory=list)
     max_kg_share: float = 0.0
-
-    def throughput(self) -> float:
-        return self.processed_tuples / max(self.ticks, 1)
 
 
 #: Size of the EngineMetrics.hot_keygroups top-k gauge.
@@ -398,21 +407,26 @@ class Engine:
             # construction — the explicit use_fn_jit=True opt-in — so the
             # dtype-semantics flip happens at a predictable time instead of
             # whenever the first segment hits the compiled tier mid-run.
+            import jax
+
             from repro.engine import jitexec  # noqa: F401
 
+            enable_spans()
             # With jax already up, routing sorts go through the bucketed
             # radix-sort dispatcher (Pallas kernel on TPU; the CPU reference
             # is the bit-identical stable argsort numpy would have run).
             from repro.kernels.radix_sort import bucket_argsort
 
             self._bucket_argsort = bucket_argsort
+            # A backend that fails to initialize raises here.
+            on_tpu = jax.default_backend() == "tpu"
+            # The dispatcher runs its kernel, and fetches the order, on a
+            # TPU only.
+            self._argsort_fetches = int(on_tpu)
             if kernel_stats is None:
                 # The device tiers route through the Pallas partition kernel
-                # exactly when jax runs on a TPU — decided once, here; a
-                # backend that fails to initialize raises.
-                import jax
-
-                kernel_stats = jax.default_backend() == "tpu"
+                # exactly when jax runs on a TPU — decided once, here.
+                kernel_stats = on_tpu
         else:
             self._bucket_argsort = None
         # The numpy tiers never touch jax unless kernel_stats=True asks.
@@ -544,11 +558,15 @@ class Engine:
                     from repro.kernels.keygroup_partition import keygroup_partition
 
                     self._partition_kernel = keygroup_partition
-                return self._partition_kernel(
-                    keys, spec.num_keygroups, base=self.topology.kg_base(op)
-                )
+                with span("route.partition"):
+                    # Two fetches: the key-group ids and the histogram.
+                    self.metrics.device_fetches += 2
+                    return self._partition_kernel(
+                        keys, spec.num_keygroups, base=self.topology.kg_base(op)
+                    )
         return self.topology.keygroups_of(op, keys, values), None
 
+    @traced("route")
     def _route_batch(
         self,
         op: int,
@@ -596,21 +614,23 @@ class Engine:
             local = local_of[kgs]
         tup_nodes = self.router.nodes_of(kgs)
         if src_kgs is not None:
-            window.record_send_pairs(src_kgs, kgs)
-            cross = tup_nodes != src_nodes
-            cs_src = src_kgs[cross]
-            n_cross = len(cs_src)
-            if n_cross:
-                # Cross-node: serialization at src, deserialization at dst,
-                # plus network bytes on both (paper §4.3.2 rationale) — one
-                # histogram per side, then vector adds on the usage rows.
-                g = len(self._arrivals)
-                both = np.bincount(cs_src, minlength=g)
-                both += np.bincount(kgs[cross], minlength=g)
-                self._cpu_usage += both * self.ser_cost
-                window.kg_usage["network"] += both
-            self.metrics.cross_node_tuples += n_cross
-            self.metrics.intra_node_tuples += n - n_cross
+            with span("route.stats"):
+                window.record_send_pairs(src_kgs, kgs)
+                cross = tup_nodes != src_nodes
+                cs_src = src_kgs[cross]
+                n_cross = len(cs_src)
+                if n_cross:
+                    # Cross-node: serialization at src, deserialization at
+                    # dst, plus network bytes on both (paper §4.3.2
+                    # rationale) — one histogram per side, then vector adds
+                    # on the usage rows.
+                    g = len(self._arrivals)
+                    both = np.bincount(cs_src, minlength=g)
+                    both += np.bincount(kgs[cross], minlength=g)
+                    self._cpu_usage += both * self.ser_cost
+                    window.kg_usage["network"] += both
+                self.metrics.cross_node_tuples += n_cross
+                self.metrics.intra_node_tuples += n - n_cross
         # Sort tuples by the (destination node, key group) composite so each
         # node's work is ONE contiguous slice of the sorted arrays and runs
         # are adjacent within it — segments can then be drained with whole-
@@ -637,10 +657,12 @@ class Engine:
             # TPU, the identical stable argsort on CPU).
             small = self.num_nodes * nkg <= 32767
             if self._bucket_argsort is not None:
-                order = self._bucket_argsort(
-                    comp.astype(np.int16) if small else comp,
-                    self.num_nodes * nkg,
-                )
+                with span("route.argsort"):
+                    self.metrics.device_fetches += self._argsort_fetches
+                    order = self._bucket_argsort(
+                        comp.astype(np.int16) if small else comp,
+                        self.num_nodes * nkg,
+                    )
             elif small:
                 order = np.argsort(comp.astype(np.int16), kind="stable")
             else:
@@ -780,14 +802,15 @@ class Engine:
         jit_on = self._jit_on
         if jit_on:
             self._sink_tail_base = len(self.metrics.sink_outputs)
-        for node, q in enumerate(self._queues):
-            if not q or not alive[node]:
-                continue
-            budget = service_rate * caps[node]
-            if q.__class__ is SoAWorkQueue:
-                self._drain_soa(node, q, budget, drained_kgs, drained_costs)
-            else:
-                q.drain(budget, self._process, node, drained_kgs, drained_costs)
+        with span("engine.drain"):
+            for node, q in enumerate(self._queues):
+                if not q or not alive[node]:
+                    continue
+                budget = service_rate * caps[node]
+                if q.__class__ is SoAWorkQueue:
+                    self._drain_soa(node, q, budget, drained_kgs, drained_costs)
+                else:
+                    q.drain(budget, self._process, node, drained_kgs, drained_costs)
         if jit_on:
             if self._jit_batch:
                 self._flush_jit_batch()
@@ -1093,18 +1116,19 @@ class Engine:
                 )
                 parts = [(entries[0], outputs, out_lens)]
             else:
-                cat_k = np.concatenate([e[4] for e in entries])
-                cat_v = np.concatenate([e[5] for e in entries])
-                cat_t = np.concatenate([e[6] for e in entries])
-                rk, rs, re_ = [], [], []
-                off = 0
-                bounds = []
-                for e in entries:
-                    rk.extend(e[1])
-                    rs.extend(a + off for a in e[2])
-                    re_.extend(z + off for z in e[3])
-                    bounds.append((len(e[1]), len(e[4])))
-                    off += len(e[4])
+                with span("jit.prepare"):
+                    cat_k = np.concatenate([e[4] for e in entries])
+                    cat_v = np.concatenate([e[5] for e in entries])
+                    cat_t = np.concatenate([e[6] for e in entries])
+                    rk, rs, re_ = [], [], []
+                    off = 0
+                    bounds = []
+                    for e in entries:
+                        rk.extend(e[1])
+                        rs.extend(a + off for a in e[2])
+                        re_.extend(z + off for z in e[3])
+                        bounds.append((len(e[1]), len(e[4])))
+                        off += len(e[4])
                 outputs, out_lens = self._jit_exec(
                     op, rk, rs, re_, cat_k, cat_v, cat_t
                 )
@@ -1147,13 +1171,14 @@ class Engine:
                 if term:
                     metrics.sink_tuples += n_out
                     if cell is not None:
-                        cell.extend(
-                            zip(
-                                outputs[0].tolist(),
-                                outputs[1].tolist(),
-                                outputs[2].tolist(),
+                        with span("sink.collect"):
+                            cell.extend(
+                                zip(
+                                    outputs[0].tolist(),
+                                    outputs[1].tolist(),
+                                    outputs[2].tolist(),
+                                )
                             )
-                        )
                 else:
                     if out_lens is None:
                         lens = np.subtract(re_, rs)
@@ -1162,6 +1187,7 @@ class Engine:
                     kg_arr = np.repeat(np.asarray(rk, dtype=np.int64), lens)
                     cell.append((outputs, kg_arr, node))
 
+    @traced("sink.collect")
     def _expand_sink_cells(self) -> None:
         """Flatten this tick's sink placeholder cells in place (cells were
         appended in drain order; only the tick's tail is rebuilt)."""
@@ -1253,6 +1279,7 @@ class Engine:
             values = schema.typed_values(values)
         return keys, values, ts
 
+    @traced("engine.flush_outputs")
     def _flush_outputs(self) -> None:
         """Route this tick's accumulated outputs, one batch per operator.
 
@@ -1333,25 +1360,27 @@ class Engine:
             # Statistics (and any external reader of the store) see dicts:
             # refresh every column-authoritative key group before |σ_k| is
             # re-measured below.
-            self._jit.sync_store()
-        ticks = max(self._ticks_this_period, 1)
-        scale = 100.0 / (ticks * self.service_rate)  # → % of a reference node
-        kg_load, out_pairs, _resource = self.window.fold(scale_to_percent=scale)
-        state = ClusterState.create(
-            self.num_nodes,
-            self._kg_op,
-            kg_load,
-            self.router.table.copy(),
-            kg_state_bytes=self.store.state_bytes(refresh=True),
-            out_rates=out_pairs,
-            downstream=self._downstream,
-            capacity=self.capacity.copy(),
-            kg_tuple_rate=self.window.kg_arrivals / ticks,
-        )
-        state.alive = self.alive.copy()
-        self.metrics.hot_keygroups, self.metrics.max_kg_share = hot_key_summary(
-            self.window.kg_arrivals
-        )
+            with span("period.sync_store"):
+                self._jit.sync_store()
+        with span("period.fold"):
+            ticks = max(self._ticks_this_period, 1)
+            scale = 100.0 / (ticks * self.service_rate)  # → % of a reference node
+            kg_load, out_pairs, _resource = self.window.fold(scale_to_percent=scale)
+            state = ClusterState.create(
+                self.num_nodes,
+                self._kg_op,
+                kg_load,
+                self.router.table.copy(),
+                kg_state_bytes=self.store.state_bytes(refresh=True),
+                out_rates=out_pairs,
+                downstream=self._downstream,
+                capacity=self.capacity.copy(),
+                kg_tuple_rate=self.window.kg_arrivals / ticks,
+            )
+            state.alive = self.alive.copy()
+            self.metrics.hot_keygroups, self.metrics.max_kg_share = (
+                hot_key_summary(self.window.kg_arrivals)
+            )
         self.window.reset()
         self._ticks_this_period = 0
         if self._checkpointer is not None:

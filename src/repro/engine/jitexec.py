@@ -66,6 +66,7 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp  # noqa: E402  (after the x64 flag, deliberately)
 
 from repro.engine.topology import StateField, Topology  # noqa: E402
+from repro.engine.tracing import span, traced  # noqa: E402
 from repro.kernels.radix_sort import radix_argsort_jax  # noqa: E402
 
 # Sentinel for unused table slots and padding tuple codes.  Real codes must
@@ -75,6 +76,8 @@ EMPTY_CODE = np.iinfo(np.int64).max
 _MIN_TUPLE_BUCKET = 16
 _MIN_RUN_BUCKET = 4
 _MIN_TABLE_CAP = 64
+# Device arrays read back to materialise one state field on the host.
+_FIELD_FETCHES = {"scalar": 1, "vector": 2, "table": 4}
 
 
 def _bucket(x: int, lo: int) -> int:
@@ -608,6 +611,7 @@ class JitRuntime:
                 )
 
     # ------------------------------------------------------------ execution
+    @traced("jit.call")
     def execute(self, op, kgs, starts, ends, keys, values, ts):
         """Run one contiguous (node, operator) segment through the jit tier.
 
@@ -618,7 +622,7 @@ class JitRuntime:
         ost = self._by_op[op]
         n = len(keys)
         r = len(kgs)
-        # One host↔device boundary per call (dispatch + output fetch).
+        # One launch per call (its result reads count as device_fetches).
         self._metrics.jit_host_syncs += 1
         if ost.has_vectors and len(set(kgs)) != r:
             # Window rings read pre-call occupancy per key group, so a call
@@ -647,33 +651,35 @@ class JitRuntime:
         st_arr = np.asarray(starts, dtype=np.int64)
         en_arr = np.asarray(ends, dtype=np.int64)
         if ost.fields:
-            self._prepare_state(ost, lkgs, n)
+            with span("jit.prepare"):
+                self._prepare_state(ost, lkgs, n)
         if ost.shards:
             return self._execute_sharded_tables(
                 ost, lkgs, st_arr, en_arr, keys, values, ts, n, r
             )
-        # Fresh padded buffers per call: jax zero-copies numpy on CPU, so a
-        # reused scratch could be read after we overwrite it.
-        kg_pad = np.full(rb, ost.nkg, dtype=np.int64)
-        kg_pad[:r] = lkgs
-        s_pad = np.full(rb, n, dtype=np.int64)
-        s_pad[:r] = st_arr
-        e_pad = np.full(rb, n, dtype=np.int64)
-        e_pad[:r] = en_arr
-        key_pad = np.zeros(nb, dtype=keys.dtype)
-        key_pad[:n] = keys
-        ts_pad = np.zeros(nb, dtype=np.float64)
-        ts_pad[:n] = ts
-        if ost.value_names is None:
-            v_arg = np.zeros(nb, dtype=values.dtype)
-            v_arg[:n] = values
-        else:
-            v_arg = {}
-            for name in ost.value_names:
-                col = values[name]
-                pad = np.zeros(nb, dtype=col.dtype)
-                pad[:n] = col
-                v_arg[name] = pad
+        with span("jit.prepare"):
+            # Fresh padded buffers per call: jax zero-copies numpy on CPU,
+            # so a reused scratch could be read after we overwrite it.
+            kg_pad = np.full(rb, ost.nkg, dtype=np.int64)
+            kg_pad[:r] = lkgs
+            s_pad = np.full(rb, n, dtype=np.int64)
+            s_pad[:r] = st_arr
+            e_pad = np.full(rb, n, dtype=np.int64)
+            e_pad[:r] = en_arr
+            key_pad = np.zeros(nb, dtype=keys.dtype)
+            key_pad[:n] = keys
+            ts_pad = np.zeros(nb, dtype=np.float64)
+            ts_pad[:n] = ts
+            if ost.value_names is None:
+                v_arg = np.zeros(nb, dtype=values.dtype)
+                v_arg[:n] = values
+            else:
+                v_arg = {}
+                for name in ost.value_names:
+                    col = values[name]
+                    pad = np.zeros(nb, dtype=col.dtype)
+                    pad[:n] = col
+                    v_arg[name] = pad
         fn = ost.spec.fn_jit
         use_shard = (
             self._mesh is not None
@@ -692,46 +698,92 @@ class JitRuntime:
         else:
             jitted = _jitted_plain(fn)
         key = (nb, rb, tuple(sorted(ost.caps.items())), use_shard)
-        first = key not in ost.seen_keys
-        if first:
-            ost.seen_keys.add(key)
-            self._metrics.jit_compiles += 1
-            t0 = time.perf_counter()
-        result = jitted(ost.cols, kg_pad, s_pad, e_pad, key_pad, v_arg, ts_pad)
-        if first:
-            jax.block_until_ready(result)
-            self.compile_seconds += time.perf_counter() - t0
+        result = self._launch(
+            ost, key, jitted, (kg_pad, s_pad, e_pad, key_pad, v_arg, ts_pad)
+        )
         state_new, outputs, out_counts = result
         ost.cols = state_new
-        for f in ost.fields:
-            if f.kind == "table":
-                ost.cnt_host[f.name] = int(state_new[f.name].cnt)
         ost.col_auth[lkgs] = True
         self._metrics.jit_calls += 1
         self._metrics.jit_tuples += n
-        if outputs is None:
-            return None, None
-        ok, ov, ot = outputs
-        if out_counts is None:
-            total, lens = n, None
-        else:
-            lens_arr = np.asarray(out_counts)[:r]
-            total = int(lens_arr.sum())
-            lens = lens_arr.tolist()
-        ok_np = np.asarray(ok)[:total]
-        ot_np = np.asarray(ot)[:total]
-        if isinstance(ov, dict):
-            if ost.out_dtype is None:
-                raise ValueError(
-                    f"fn_jit of operator {ost.spec.name!r} returned record "
-                    "columns but the operator declares no out_schema"
-                )
-            ov_np = np.empty(total, dtype=ost.out_dtype)
-            for name in ost.out_names:
-                ov_np[name] = np.asarray(ov[name])[:total]
-        else:
-            ov_np = np.asarray(ov)[:total]
+        with span("jit.fetch"):
+            self._fetch_counts(ost, state_new)
+            if outputs is None:
+                return None, None
+            ok, ov, ot = outputs
+            if out_counts is None:
+                total, lens = n, None
+            else:
+                self._metrics.device_fetches += 1
+                lens_arr = np.asarray(out_counts)[:r]
+                total = int(lens_arr.sum())
+                lens = lens_arr.tolist()
+            ok_np = np.asarray(ok)[:total]
+            ot_np = np.asarray(ot)[:total]
+            ov_np = self._fetch_values(ost, ov, total)
+            self._metrics.device_fetches += 2
         return (ok_np, ov_np, ot_np), lens
+
+    def _launch(self, ost: _OpState, key, jitted, args):
+        """Dispatch one jitted call; a new ``key`` compiles, and its call
+        waits for the device so the compile is timed."""
+        with span("jit.launch"):
+            if key in ost.seen_keys:
+                return jitted(ost.cols, *args)
+            ost.seen_keys.add(key)
+            self._metrics.jit_compiles += 1
+            with span("jit.compile"):
+                t0 = time.perf_counter()
+                result = jitted(ost.cols, *args)
+                jax.block_until_ready(result)
+                self.compile_seconds += time.perf_counter() - t0
+            return result
+
+    def _fetch_counts(self, ost: _OpState, state_new: dict) -> None:
+        """Read each keyed table's entry count back (one fetch each, per
+        shard when sharded) and refresh the table gauges (every capacity
+        bump and dict push comes before a call, so the gauges see them)."""
+        for f in ost.fields:
+            if f.kind == "table":
+                cnt = state_new[f.name].cnt
+                ost.cnt_host[f.name] = (
+                    np.asarray(cnt, dtype=np.int64) if ost.shards else int(cnt)
+                )
+                self._metrics.device_fetches += 1
+        if ost.has_tables:
+            self._table_gauges()
+
+    def _fetch_values(self, ost: _OpState, ov, total: int, idx=slice(None)):
+        """The output value columns on the host: the first ``total`` rows,
+        then ``idx`` (one fetch per column)."""
+        if not isinstance(ov, dict):
+            self._metrics.device_fetches += 1
+            return np.asarray(ov)[:total][idx]
+        if ost.out_dtype is None:
+            raise ValueError(
+                f"fn_jit of operator {ost.spec.name!r} returned record "
+                "columns but the operator declares no out_schema"
+            )
+        ov_np = np.empty(total, dtype=ost.out_dtype)
+        for name in ost.out_names:
+            ov_np[name] = np.asarray(ov[name])[:total][idx]
+        self._metrics.device_fetches += len(ost.out_names)
+        return ov_np
+
+    def _table_gauges(self) -> None:
+        """Entries and slots summed over every keyed table (host counts)."""
+        entries = capacity = 0
+        for ost in self._by_op.values():
+            for name, cap in ost.caps.items():
+                cnt = ost.cnt_host[name]
+                if ost.shards:
+                    entries += int(np.sum(cnt))
+                    capacity += cap * ost.shards
+                else:
+                    entries += cnt
+                    capacity += cap
+        self._metrics.table_entries = entries
+        self._metrics.table_capacity = capacity
 
     def _execute_sharded_tables(
         self, ost, lkgs, st_arr, en_arr, keys, values, ts, n, r
@@ -749,55 +801,56 @@ class JitRuntime:
         float policy.  Outputs come back positionally over the permuted
         tuples and are ungathered on the host (1:1 bodies only).
         """
-        d = ost.shards
-        shard_ids = ost.shard_of(lkgs)
-        order_runs = np.argsort(shard_ids, kind="stable")
-        lens = en_arr - st_arr
-        if r:
-            perm = np.concatenate(
-                [np.arange(st_arr[i], en_arr[i]) for i in order_runs]
-            )
-        else:
-            perm = np.empty(0, np.int64)
-        rs_per = np.bincount(shard_ids, minlength=d)
-        rbs = _bucket(int(rs_per.max()) if r else 1, _MIN_RUN_BUCKET)
-        nb = _bucket(n, _MIN_TUPLE_BUCKET)
-        new_lens = lens[order_runs]
-        new_ends = np.cumsum(new_lens)
-        new_starts = new_ends - new_lens
-        kg_pad = np.full(d * rbs, ost.nkg, dtype=np.int64)
-        s_pad = np.empty(d * rbs, dtype=np.int64)
-        e_pad = np.empty(d * rbs, dtype=np.int64)
-        pos = 0
-        off = 0
-        for s in range(d):
-            cnt_s = int(rs_per[s])
-            blk_end = int(new_ends[pos + cnt_s - 1]) if cnt_s else off
-            base_i = s * rbs
-            s_pad[base_i : base_i + rbs] = blk_end
-            e_pad[base_i : base_i + rbs] = blk_end
-            if cnt_s:
-                kg_pad[base_i : base_i + cnt_s] = lkgs[
-                    order_runs[pos : pos + cnt_s]
-                ]
-                s_pad[base_i : base_i + cnt_s] = new_starts[pos : pos + cnt_s]
-                e_pad[base_i : base_i + cnt_s] = new_ends[pos : pos + cnt_s]
-            pos += cnt_s
-            off = blk_end
-        key_pad = np.zeros(nb, dtype=keys.dtype)
-        key_pad[:n] = np.asarray(keys)[perm]
-        ts_pad = np.zeros(nb, dtype=np.float64)
-        ts_pad[:n] = np.asarray(ts)[perm]
-        if ost.value_names is None:
-            v_arg = np.zeros(nb, dtype=values.dtype)
-            v_arg[:n] = np.asarray(values)[perm]
-        else:
-            v_arg = {}
-            for name in ost.value_names:
-                col = values[name]
-                pad = np.zeros(nb, dtype=col.dtype)
-                pad[:n] = np.asarray(col)[perm]
-                v_arg[name] = pad
+        with span("jit.prepare"):
+            d = ost.shards
+            shard_ids = ost.shard_of(lkgs)
+            order_runs = np.argsort(shard_ids, kind="stable")
+            lens = en_arr - st_arr
+            if r:
+                perm = np.concatenate(
+                    [np.arange(st_arr[i], en_arr[i]) for i in order_runs]
+                )
+            else:
+                perm = np.empty(0, np.int64)
+            rs_per = np.bincount(shard_ids, minlength=d)
+            rbs = _bucket(int(rs_per.max()) if r else 1, _MIN_RUN_BUCKET)
+            nb = _bucket(n, _MIN_TUPLE_BUCKET)
+            new_lens = lens[order_runs]
+            new_ends = np.cumsum(new_lens)
+            new_starts = new_ends - new_lens
+            kg_pad = np.full(d * rbs, ost.nkg, dtype=np.int64)
+            s_pad = np.empty(d * rbs, dtype=np.int64)
+            e_pad = np.empty(d * rbs, dtype=np.int64)
+            pos = 0
+            off = 0
+            for s in range(d):
+                cnt_s = int(rs_per[s])
+                blk_end = int(new_ends[pos + cnt_s - 1]) if cnt_s else off
+                base_i = s * rbs
+                s_pad[base_i : base_i + rbs] = blk_end
+                e_pad[base_i : base_i + rbs] = blk_end
+                if cnt_s:
+                    kg_pad[base_i : base_i + cnt_s] = lkgs[
+                        order_runs[pos : pos + cnt_s]
+                    ]
+                    s_pad[base_i : base_i + cnt_s] = new_starts[pos : pos + cnt_s]
+                    e_pad[base_i : base_i + cnt_s] = new_ends[pos : pos + cnt_s]
+                pos += cnt_s
+                off = blk_end
+            key_pad = np.zeros(nb, dtype=keys.dtype)
+            key_pad[:n] = np.asarray(keys)[perm]
+            ts_pad = np.zeros(nb, dtype=np.float64)
+            ts_pad[:n] = np.asarray(ts)[perm]
+            if ost.value_names is None:
+                v_arg = np.zeros(nb, dtype=values.dtype)
+                v_arg[:n] = np.asarray(values)[perm]
+            else:
+                v_arg = {}
+                for name in ost.value_names:
+                    col = values[name]
+                    pad = np.zeros(nb, dtype=col.dtype)
+                    pad[:n] = np.asarray(col)[perm]
+                    v_arg[name] = pad
         fn = ost.spec.fn_jit
         table_names = tuple(
             sorted(f.name for f in ost.fields if f.kind == "table")
@@ -808,43 +861,25 @@ class JitRuntime:
         cache_key = (
             nb, rbs, tuple(sorted(ost.caps.items())), "shard_tab"
         )
-        first = cache_key not in ost.seen_keys
-        if first:
-            ost.seen_keys.add(cache_key)
-            self._metrics.jit_compiles += 1
-            t0 = time.perf_counter()
-        result = jitted(ost.cols, kg_pad, s_pad, e_pad, key_pad, v_arg, ts_pad)
-        if first:
-            jax.block_until_ready(result)
-            self.compile_seconds += time.perf_counter() - t0
+        result = self._launch(
+            ost, cache_key, jitted, (kg_pad, s_pad, e_pad, key_pad, v_arg, ts_pad)
+        )
         state_new, outputs, _ = result
         ost.cols = state_new
-        for f in ost.fields:
-            if f.kind == "table":
-                ost.cnt_host[f.name] = np.asarray(
-                    state_new[f.name].cnt, dtype=np.int64
-                )
         ost.col_auth[lkgs] = True
         self._metrics.jit_calls += 1
         self._metrics.jit_tuples += n
-        if outputs is None:
-            return None, None
-        ok, ov, ot = outputs
-        inv = np.empty(n, dtype=np.int64)
-        inv[perm] = np.arange(n)
-        ok_np = np.asarray(ok)[:n][inv]
-        ot_np = np.asarray(ot)[:n][inv]
-        if isinstance(ov, dict):
-            if ost.out_dtype is None:
-                raise ValueError(
-                    f"fn_jit of operator {ost.spec.name!r} returned record "
-                    "columns but the operator declares no out_schema"
-                )
-            ov_np = np.empty(n, dtype=ost.out_dtype)
-            for name in ost.out_names:
-                ov_np[name] = np.asarray(ov[name])[:n][inv]
-        else:
-            ov_np = np.asarray(ov)[:n][inv]
+        with span("jit.fetch"):
+            self._fetch_counts(ost, state_new)
+            if outputs is None:
+                return None, None
+            ok, ov, ot = outputs
+            inv = np.empty(n, dtype=np.int64)
+            inv[perm] = np.arange(n)
+            ok_np = np.asarray(ok)[:n][inv]
+            ot_np = np.asarray(ot)[:n][inv]
+            ov_np = self._fetch_values(ost, ov, n, inv)
+            self._metrics.device_fetches += 2
         return (ok_np, ov_np, ot_np), None
 
     # ----------------------------------------------------- state coherence
@@ -863,6 +898,7 @@ class JitRuntime:
             if need > ost.caps[f.name]:
                 self._grow(ost, f, need)
 
+    @traced("jit.grow")
     def _grow(self, ost: _OpState, f: StateField, cap_needed: int) -> None:
         """Extend the slab; the sorted view's EMPTY tail (ascending by slab
         index) extends with the fresh indices — no re-sort needed."""
@@ -870,6 +906,8 @@ class JitRuntime:
         t = ost.cols[f.name]
         old = ost.caps[f.name]
         pad = new_cap - old
+        self._metrics.table_grows += 1
+        self._metrics.device_fetches += 1  # the codes, padded on the host
         if ost.shards:
             d = ost.shards
             codes = np.full((d, new_cap), EMPTY_CODE, dtype=np.int64)
@@ -951,6 +989,7 @@ class JitRuntime:
             vals = np.asarray(t.vals)[:cnt]
             seq = np.asarray(t.seq)[:cnt]
             owner = np.asarray(t.owner)[:cnt]
+            self._metrics.device_fetches += 5  # four columns and the epoch
             keep = ~np.isin(owner, pend)
             new_c, new_v, new_o = [], [], []
             enc = f.key_encode
@@ -966,6 +1005,7 @@ class JitRuntime:
             if total > cap:
                 cap = _bucket(total, _MIN_TABLE_CAP)
                 ost.caps[f.name] = cap
+                self._metrics.table_grows += 1
             pc = np.full(cap, EMPTY_CODE, dtype=np.int64)
             pv = np.zeros(cap, dtype=f.dtype)
             ps = np.zeros(cap, dtype=np.int64)
@@ -1012,6 +1052,7 @@ class JitRuntime:
         owner_h = np.asarray(t.owner)
         perm_h = np.asarray(t.perm)
         epoch_h = np.asarray(t.epoch).copy()
+        self._metrics.device_fetches += 6
         shard_ids = ost.shard_of(pend)
         old_cap = codes_h.shape[1]
         cap = ost.caps[f.name]
@@ -1032,6 +1073,8 @@ class JitRuntime:
             per_shard[s] = (cnt, keep, new_c, new_v, new_o, total)
             if total > cap:
                 cap = _bucket(total, _MIN_TABLE_CAP)
+        if cap > old_cap:
+            self._metrics.table_grows += 1
         ost.caps[f.name] = cap
         pc = np.full((d, cap), EMPTY_CODE, dtype=np.int64)
         pv = np.zeros((d, cap), dtype=f.dtype)
@@ -1100,6 +1143,7 @@ class JitRuntime:
     def _host_cols(self, ost: _OpState) -> dict:
         host = {}
         for f in ost.fields:
+            self._metrics.device_fetches += _FIELD_FETCHES[f.kind]
             if f.kind == "scalar":
                 host[f.name] = np.asarray(ost.cols[f.name])
             elif f.kind == "vector":
