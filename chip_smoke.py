@@ -26,7 +26,8 @@ allocation must match exactly, except float values, which may differ by
 
 ``--chips 4`` runs only Job 3 on ``ExecutionConfig.jit(mesh=<4-device
 "nodes" mesh>)`` against the same job on one chip, and checks that every
-device holds part of the keyed state.
+device holds part of the keyed state and that a call whose new keys
+overflowed a shard's table ran again on grown tables.
 
 Earlier lines are bring-up facts (compile seconds, tuples, migrations,
 wall time); the last line is ``{"ok": true, "device": {...}}``, printed
@@ -393,6 +394,10 @@ def phase_mesh(seed: int, *, nodes: int = NODES, kgs: int = KGS,
     drive(one, _Replay(rec.results), "airline", batches, periods, ticks)
     info = _engine_info(dev, rows, wall)
     info.update(compare(dev, one))
+    # The first batch brings more keys than a shard's fresh table holds, so
+    # the sharded call must have run again on grown tables.
+    if dev.metrics.table_retries == 0:
+        raise AssertionError("no sharded call overflowed a keyed table")
     # Each device must hold part of every keyed table: the key-group-sharded
     # slabs carry one shard per device, each with entries of its own.
     spread = {}
@@ -417,6 +422,8 @@ def _engine_info(engine, rows, wall: float) -> dict:
         "migrations_per_period": [r.num_migrations for r in rows],
         "jit_compiles": m.jit_compiles,
         "jit_host_syncs": m.jit_host_syncs,
+        "table_grows": m.table_grows,
+        "table_retries": m.table_retries,
         "fused_ticks": m.fused_ticks,
         "kernel_stats": engine.kernel_stats,
     }

@@ -47,11 +47,13 @@ class PeriodMetrics:
     num_unsplits: int = 0
     #: Worker recoveries (supervised respawn + rewind) completed this period.
     num_recoveries: int = 0
-    #: Blocking device→host reads and keyed-table capacity bumps this period
-    #: (EngineMetrics.device_fetches, table_grows), and the keyed device
+    #: Blocking device→host reads, keyed-table capacity bumps and jit calls
+    #: re-run after a table overflow this period (EngineMetrics.
+    #: device_fetches, table_grows, table_retries), and the keyed device
     #: tables' entries and slots at its end.
     device_fetches: int = 0
     table_grows: int = 0
+    table_retries: int = 0
     table_entries: int = 0
     table_capacity: int = 0
 
@@ -86,6 +88,7 @@ class Controller:
         counters = self.engine.metrics
         fetches_before = counters.device_fetches
         grows_before = counters.table_grows
+        retries_before = counters.table_retries
         self.run_ticks(self.config.ticks_per_period)
         snapshot = self.engine.end_period()
 
@@ -165,6 +168,7 @@ class Controller:
             ),
             device_fetches=counters.device_fetches - fetches_before,
             table_grows=counters.table_grows - grows_before,
+            table_retries=counters.table_retries - retries_before,
             table_entries=counters.table_entries,
             table_capacity=counters.table_capacity,
         )

@@ -156,11 +156,12 @@ class EngineMetrics:
     jit_calls: int = 0
     jit_tuples: int = 0
     jit_compiles: int = 0
-    # Launches of the compiled tier: one per per-operator jit call, one per
-    # fused superstep tick, one per run_supersteps(K) scan — the metric that
-    # proves the superstep path's O(1) launches per K ticks against the
-    # per-operator tier's O(ops·K).  It counts calls, not reads: a call
-    # fetches several result arrays (device_fetches).
+    # Launches of the compiled tier: one per per-operator jit call (and one
+    # per re-run after a keyed-table overflow), one per fused superstep
+    # tick, one per run_supersteps(K) scan — the metric that proves the
+    # superstep path's O(1) launches per K ticks against the per-operator
+    # tier's O(ops·K).  It counts calls, not reads: a call fetches several
+    # result arrays (device_fetches).
     jit_host_syncs: int = 0
     # Blocking device→host reads on the jit tier's tick and end_period
     # paths, counted where each happens: jit results, keyed-table reads
@@ -168,10 +169,12 @@ class EngineMetrics:
     # kernels' results.
     device_fetches: int = 0
     # Keyed device tables summed over every table: entries held and slots
-    # allocated (gauges, from the host's own counts), and capacity bumps.
+    # allocated (gauges, from the host's own counts), capacity bumps, and
+    # jit calls run again because their new keys overflowed a table.
     table_entries: int = 0
     table_capacity: int = 0
     table_grows: int = 0
+    table_retries: int = 0
     # Ticks executed as fused device programs: one per fused superstep tick
     # that reached the device, K per run_supersteps(K) scan (0 whenever the
     # superstep runtime fell back to the classic tick).

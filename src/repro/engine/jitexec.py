@@ -46,6 +46,7 @@ float is held to the tolerance (``chip_smoke.py``); integers stay exact.
 
 from __future__ import annotations
 
+import functools
 import time
 import weakref
 from typing import NamedTuple, Optional
@@ -85,6 +86,11 @@ def _bucket(x: int, lo: int) -> int:
     while b < x:
         b <<= 1
     return b
+
+
+def _table_cap(entries: int) -> int:
+    """A keyed table's capacity: twice its entries, in a power of two."""
+    return _bucket(2 * entries, _MIN_TABLE_CAP)
 
 
 class TableState(NamedTuple):
@@ -217,6 +223,12 @@ def keyed_running_sum(
     sort), and the kernel skips its own sort.  The permutation must be
     exactly the stable argsort — stability defines it uniquely, so any
     conforming producer yields bit-identical results.
+
+    Overflow: when the segment's new codes do not fit (``cnt + new >
+    capacity``) the returned ``cnt`` reads ``cnt + new``, above the
+    capacity, and the rest of the table is not valid: the jit wrappers
+    keep the call's input state instead, so the caller can grow the table
+    and run the segment again.
     """
     nb = codes.shape[0]
     cap = table.codes.shape[0]
@@ -310,10 +322,40 @@ def keyed_running_sum(
 _JITTED: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 
 
+def _hold_on_overflow(state: dict, new: dict) -> dict:
+    """Keep a call's whole state as it came in once any keyed table
+    overflowed (its ``cnt`` above its capacity), so the call can run again
+    on grown tables and apply once.  The counts pass through: they carry
+    the overflow to the host, which puts them back."""
+    tables = [v for v in new.values() if isinstance(v, TableState)]
+    if not tables:
+        return new
+    over = jnp.any(
+        jnp.stack([jnp.any(t.cnt > t.codes.shape[-1]) for t in tables])
+    )
+
+    def hold(old, nw):
+        return jnp.where(over, old, nw)
+
+    out = {}
+    for name, nw in new.items():
+        held = jax.tree_util.tree_map(hold, state[name], nw)
+        out[name] = (
+            held._replace(cnt=nw.cnt) if isinstance(nw, TableState) else held
+        )
+    return out
+
+
 def _jitted_plain(fn):
     entry = _JITTED.setdefault(fn, {})
     if "plain" not in entry:
-        entry["plain"] = jax.jit(fn, donate_argnums=0)
+
+        @functools.wraps(fn)  # the device ops keep the body's name
+        def call(state, *args):
+            state2, outputs, out_counts = fn(state, *args)
+            return _hold_on_overflow(state, state2), outputs, out_counts
+
+        entry["plain"] = jax.jit(call, donate_argnums=0)
     return entry["plain"]
 
 
@@ -475,7 +517,7 @@ def _jitted_sharded_tables(fn, mesh, axis, table_names):
                 in_specs=(state_spec, P(axis), P(axis), P(axis), P(), P(), P()),
                 out_specs=(state_spec, P()),
             )(state, kgs, starts, ends, keys, values, ts)
-            return state_m, outputs, None
+            return _hold_on_overflow(state, state_m), outputs, None
 
         entry[key] = jax.jit(call)
     return entry[key]
@@ -652,7 +694,7 @@ class JitRuntime:
         en_arr = np.asarray(ends, dtype=np.int64)
         if ost.fields:
             with span("jit.prepare"):
-                self._prepare_state(ost, lkgs, n)
+                self._prepare_state(ost, lkgs)
         if ost.shards:
             return self._execute_sharded_tables(
                 ost, lkgs, st_arr, en_arr, keys, values, ts, n, r
@@ -697,17 +739,14 @@ class JitRuntime:
             jitted = _jitted_sharded(fn, self._mesh, self._mesh_axis)
         else:
             jitted = _jitted_plain(fn)
-        key = (nb, rb, tuple(sorted(ost.caps.items())), use_shard)
-        result = self._launch(
-            ost, key, jitted, (kg_pad, s_pad, e_pad, key_pad, v_arg, ts_pad)
+        _, outputs, out_counts = self._run(
+            ost, (nb, rb, use_shard), jitted,
+            (kg_pad, s_pad, e_pad, key_pad, v_arg, ts_pad),
         )
-        state_new, outputs, out_counts = result
-        ost.cols = state_new
         ost.col_auth[lkgs] = True
         self._metrics.jit_calls += 1
         self._metrics.jit_tuples += n
         with span("jit.fetch"):
-            self._fetch_counts(ost, state_new)
             if outputs is None:
                 return None, None
             ok, ov, ot = outputs
@@ -739,19 +778,54 @@ class JitRuntime:
                 self.compile_seconds += time.perf_counter() - t0
             return result
 
-    def _fetch_counts(self, ost: _OpState, state_new: dict) -> None:
+    def _run(self, ost: _OpState, shape_key: tuple, jitted, args):
+        """Launch one call and read its tables' counts back.  A call whose
+        new keys overflowed a table left the state as it was: put the
+        counts back, grow the table to twice what it would have held and
+        launch the same arguments again (the first outputs are dropped)."""
+        while True:
+            key = shape_key + (tuple(sorted(ost.caps.items())),)
+            result = self._launch(ost, key, jitted, args)
+            ost.cols = result[0]
+            with span("jit.fetch"):
+                over = self._fetch_counts(ost, result[0])
+            if not over:
+                return result
+            self._metrics.table_retries += 1
+            self._metrics.jit_host_syncs += 1
+            for f in ost.fields:
+                if f.kind == "table":
+                    t = ost.cols[f.name]
+                    before = np.asarray(ost.cnt_host[f.name], dtype=np.int32)
+                    ost.cols[f.name] = t._replace(
+                        cnt=jax.device_put(before, t.cnt.sharding)
+                    )
+                    if f.name in over:
+                        self._grow(ost, f, int(np.max(over[f.name])))
+
+    def _fetch_counts(self, ost: _OpState, state_new: dict) -> dict:
         """Read each keyed table's entry count back (one fetch each, per
-        shard when sharded) and refresh the table gauges (every capacity
-        bump and dict push comes before a call, so the gauges see them)."""
+        shard when sharded).  Returns the counts of the tables the call
+        overflowed (above their capacity), the host's counts left as
+        before the call; else refreshes the counts and the table gauges
+        (every capacity bump and dict push comes before a call, so the
+        gauges see them)."""
+        seen = {}
         for f in ost.fields:
             if f.kind == "table":
                 cnt = state_new[f.name].cnt
-                ost.cnt_host[f.name] = (
+                seen[f.name] = (
                     np.asarray(cnt, dtype=np.int64) if ost.shards else int(cnt)
                 )
                 self._metrics.device_fetches += 1
-        if ost.has_tables:
+        over = {
+            name: cnt for name, cnt in seen.items()
+            if np.max(cnt) > ost.caps[name]
+        }
+        if not over and seen:
+            ost.cnt_host.update(seen)
             self._table_gauges()
+        return over
 
     def _fetch_values(self, ost: _OpState, ov, total: int, idx=slice(None)):
         """The output value columns on the host: the first ``total`` rows,
@@ -858,19 +932,14 @@ class JitRuntime:
         jitted = _jitted_sharded_tables(
             fn, self._mesh, self._mesh_axis, table_names
         )
-        cache_key = (
-            nb, rbs, tuple(sorted(ost.caps.items())), "shard_tab"
+        _, outputs, _ = self._run(
+            ost, (nb, rbs, "shard_tab"), jitted,
+            (kg_pad, s_pad, e_pad, key_pad, v_arg, ts_pad),
         )
-        result = self._launch(
-            ost, cache_key, jitted, (kg_pad, s_pad, e_pad, key_pad, v_arg, ts_pad)
-        )
-        state_new, outputs, _ = result
-        ost.cols = state_new
         ost.col_auth[lkgs] = True
         self._metrics.jit_calls += 1
         self._metrics.jit_tuples += n
         with span("jit.fetch"):
-            self._fetch_counts(ost, state_new)
             if outputs is None:
                 return None, None
             ok, ov, ot = outputs
@@ -883,26 +952,25 @@ class JitRuntime:
         return (ok_np, ov_np, ot_np), None
 
     # ----------------------------------------------------- state coherence
-    def _prepare_state(self, ost: _OpState, lkgs: np.ndarray, n: int) -> None:
-        """Push dict-authoritative state, then size tables for this call."""
+    def _prepare_state(self, ost: _OpState, lkgs: np.ndarray) -> None:
+        """Push dict-authoritative state, then keep every table at least
+        twice its entries (the largest shard's, when sharded)."""
         pend = lkgs[~ost.col_auth[lkgs]]
         if len(pend):
             self._push(ost, pend)
         for f in ost.fields:
             if f.kind != "table":
                 continue
-            # The segment can insert at most one entry per tuple (per
-            # shard, when sharded — every shard sizes for the worst case).
-            cnt = ost.cnt_host[f.name]
-            need = (int(np.max(cnt)) if ost.shards else cnt) + n
-            if need > ost.caps[f.name]:
-                self._grow(ost, f, need)
+            cnt = int(np.max(ost.cnt_host[f.name]))
+            if _table_cap(cnt) > ost.caps[f.name]:
+                self._grow(ost, f, cnt)
 
     @traced("jit.grow")
-    def _grow(self, ost: _OpState, f: StateField, cap_needed: int) -> None:
-        """Extend the slab; the sorted view's EMPTY tail (ascending by slab
-        index) extends with the fresh indices — no re-sort needed."""
-        new_cap = _bucket(cap_needed, _MIN_TABLE_CAP)
+    def _grow(self, ost: _OpState, f: StateField, entries: int) -> None:
+        """Extend the slab to twice ``entries``; the sorted view's EMPTY
+        tail (ascending by slab index) extends with the fresh indices — no
+        re-sort needed."""
+        new_cap = _table_cap(entries)
         t = ost.cols[f.name]
         old = ost.caps[f.name]
         pad = new_cap - old
@@ -1002,9 +1070,8 @@ class JitRuntime:
             n_keep = int(keep.sum())
             total = n_keep + len(new_c)
             cap = ost.caps[f.name]
-            if total > cap:
-                cap = _bucket(total, _MIN_TABLE_CAP)
-                ost.caps[f.name] = cap
+            if _table_cap(total) > cap:
+                cap = ost.caps[f.name] = _table_cap(total)
                 self._metrics.table_grows += 1
             pc = np.full(cap, EMPTY_CODE, dtype=np.int64)
             pv = np.zeros(cap, dtype=f.dtype)
@@ -1071,8 +1138,7 @@ class JitRuntime:
                     new_o.append(lk)
             total = int(keep.sum()) + len(new_c)
             per_shard[s] = (cnt, keep, new_c, new_v, new_o, total)
-            if total > cap:
-                cap = _bucket(total, _MIN_TABLE_CAP)
+            cap = max(cap, _table_cap(total))
         if cap > old_cap:
             self._metrics.table_grows += 1
         ost.caps[f.name] = cap

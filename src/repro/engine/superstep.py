@@ -552,7 +552,7 @@ class SuperstepRuntime:
             rb = jx._bucket(r, jx._MIN_RUN_BUCKET)
             lkgs = np.asarray(rk, dtype=np.int64) - plan.base[i]
             if ost.fields:
-                jrt._prepare_state(ost, lkgs, n)
+                jrt._prepare_state(ost, lkgs)
             kg_pad = np.full(rb, ost.nkg, dtype=np.int64)
             kg_pad[:r] = lkgs
             s_pad = np.full(rb, n, dtype=np.int64)
@@ -974,7 +974,7 @@ class SuperstepRuntime:
         for i, op in enumerate(fops):
             ost = jrt._by_op[op]
             if ost.fields:
-                jrt._prepare_state(ost, np.arange(ost.nkg, dtype=np.int64), 0)
+                jrt._prepare_state(ost, np.arange(ost.nkg, dtype=np.int64))
         key = (K, nb1, eng.collect_sinks, eng.router.version)
         scan_fn = self._scan_cache.get(key)
         if scan_fn is None:

@@ -191,6 +191,168 @@ def test_table_growth_past_initial_capacity():
                 assert a[name] == b[name]
 
 
+def _sumdelay_runtime():
+    from repro.engine.executor import EngineMetrics
+    from repro.engine.jitexec import JitRuntime
+    from repro.engine.state import KeyedStore
+
+    topo = real_job_2(keygroups_per_op=4)
+    store = KeyedStore(topo.num_keygroups)
+    rt = JitRuntime(topo, store, EngineMetrics(), topo.kg_operator())
+    op = topo._resolve("sumdelay")
+    return rt, store, op, topo.kg_base(op)
+
+
+def _sumdelay_call(rt, op, base, planes, years, delays):
+    """One SumDelay call over runs of key groups plane % 4; returns the
+    tuples in run order and the running sums the call emitted."""
+    from repro.data.jobs import EXTRACT_SCHEMA
+
+    kg = planes % 4
+    order = np.argsort(kg, kind="stable")
+    values = np.zeros(len(planes), dtype=EXTRACT_SCHEMA.value)
+    values["plane"], values["year"], values["delay"] = (
+        planes[order], years[order], delays[order]
+    )
+    ends = np.cumsum(np.bincount(kg, minlength=4))
+    starts = ends - np.bincount(kg, minlength=4)
+    (_, ov, _), _ = rt.execute(
+        op, [base + k for k in range(4)], starts.tolist(), ends.tolist(),
+        values["plane"].copy(), values, np.zeros(len(planes)),
+    )
+    return values, ov["sum"]
+
+
+def test_overflowing_call_runs_again_on_a_grown_table():
+    """A first call with more new keys than the table's 64 slots leaves the
+    table as it was, grows it to twice its keys and runs again once: its
+    outputs, values and insertion order equal a left fold, and its integer
+    state equals a call on a table large enough from the start, bit for
+    bit.  A second call on the same keys neither grows nor runs again."""
+    rng = np.random.default_rng(11)
+    n = 600
+    planes = rng.integers(0, 150, size=n).astype(np.int64)
+    years = 2000 + rng.integers(0, 3, size=n).astype(np.int64)
+    keys = len(set(zip(planes.tolist(), years.tolist())))
+    batches = [
+        (planes, years, rng.normal(size=n)),
+        (planes[::-1].copy(), years[::-1].copy(), rng.normal(size=n)),
+    ]
+    rt, store, op, base = _sumdelay_runtime()
+    ref_rt, _, _, _ = _sumdelay_runtime()
+    ost = ref_rt._by_op[op]
+    ref_rt._grow(ost, ost.fields[0], 1000)  # 2,048 slots: no overflow
+    dicts = [dict() for _ in range(4)]
+    for step, batch in enumerate(batches):
+        values, sums = _sumdelay_call(rt, op, base, *batch)
+        _, ref_sums = _sumdelay_call(ref_rt, op, base, *batch)
+        expect = []
+        for p, y, dl in values[["plane", "year", "delay"]].tolist():
+            d = dicts[p % 4]
+            d[(p, y)] = d.get((p, y), 0.0) + dl
+            expect.append(d[(p, y)])
+        np.testing.assert_allclose(sums, expect, rtol=1e-9, atol=1e-12)
+        np.testing.assert_array_equal(sums, ref_sums)
+        m = rt._metrics
+        assert (m.table_retries, m.table_grows) == (1, 1)
+        assert rt._by_op[op].caps["sums"] == 1 << (2 * keys - 1).bit_length()
+        got, ref = rt._by_op[op].cols["sums"], ost.cols["sums"]
+        cnt = rt._by_op[op].cnt_host["sums"]
+        assert cnt == int(got.cnt) == sum(len(d) for d in dicts) == keys
+        for a, b in zip(got[:4], ref[:4]):  # codes, vals, seq, owner
+            np.testing.assert_array_equal(np.asarray(a)[:cnt], np.asarray(b)[:cnt])
+        assert int(got.epoch) == int(ref.epoch) == 2 + step
+        rt.sync_store()
+        for k in range(4):
+            state = store.get(base + k)["sums"]
+            assert list(state) == list(dicts[k])  # insertion order
+            np.testing.assert_allclose(
+                list(state.values()), list(dicts[k].values()), rtol=1e-9
+            )
+
+
+def _acc_fn(state, keys, values, ts):
+    state["n"] = state.get("n", 0) + len(keys)
+    sums = state.setdefault("sums", {})
+    out = np.empty(len(keys))
+    for i, (k, v) in enumerate(zip(np.asarray(keys).tolist(),
+                                   np.asarray(values).tolist())):
+        sums[k] = sums.get(k, 0.0) + v
+        out[i] = sums[k]
+    return state, (keys, out, ts)
+
+
+def _acc_jit(state, kgs, starts, ends, keys, values, ts):
+    from repro.engine import jitexec as jx
+
+    nb = keys.shape[0]
+    kg = kgs[jx.run_of_tuples(ends, nb)]
+    valid = jx.tuple_valid(starts, ends, nb)
+    table, running = jx.keyed_running_sum(state["sums"], keys, kg, values, valid)
+    n = jx.count_runs(state["n"], kgs, starts, ends)
+    return {"n": n, "sums": table}, (keys, running, ts), None
+
+
+def _acc_topo():
+    """source → a sink holding a counter and a keyed table per key group."""
+    from repro.engine.topology import (
+        OperatorSpec, Schema, StateField, StateSchema, Topology,
+    )
+
+    scalar = Schema(np.dtype(np.float64))
+    state = StateSchema((
+        StateField("n", "scalar", dtype=np.int64, py=int),
+        StateField("sums", "table", dtype=np.float64, py=float,
+                   key_encode=int, key_decode=int),
+    ))
+    t = Topology()
+    t.add_operator(OperatorSpec(
+        "src", None, num_keygroups=4, is_source=True, schema=scalar))
+    t.add_operator(OperatorSpec(
+        "acc", _acc_fn, num_keygroups=4, is_sink=True, fn_jit=_acc_jit,
+        state_schema=state, schema=scalar, out_schema=scalar))
+    t.connect("src", "acc")
+    return t
+
+
+@pytest.mark.parametrize("mesh", [False, True], ids=["plain", "mesh1"])
+def test_overflow_holds_every_state_field(mesh):
+    """An operator with a counter beside its keyed table: the call whose new
+    keys overflow the table must leave the counter as it was too, so that
+    the re-run counts each tuple once — plain and key-group-sharded."""
+    import jax
+
+    m = jax.make_mesh((1,), ("nodes",), devices=jax.devices()[:1]) if mesh else None
+    rng = np.random.default_rng(5)
+    batches = [
+        (rng.integers(0, 300, size=n).astype(np.int64), rng.normal(size=n))
+        for n in (400, 400, 100)
+    ]
+    engines = [
+        Engine(_acc_topo(), 2, service_rate=1e9, seed=0, config=cfg)
+        for cfg in (ExecutionConfig.jit(mesh=m), ExecutionConfig.typed())
+    ]
+    for eng in engines:
+        for t, (keys, vals) in enumerate(batches):
+            eng.push_source("src", keys, vals, np.full(len(keys), float(t)))
+            eng.tick()
+        for _ in range(3):
+            eng.tick()
+    jit_eng, ref = engines
+    assert jit_eng.metrics.table_retries == 1
+    jit_eng._jit.sync_store()
+    total = 0
+    for kg in range(4, 8):
+        a, b = jit_eng.store.get(kg), ref.store.get(kg)
+        assert a["n"] == b["n"]
+        total += a["n"]
+        assert list(a["sums"]) == list(b["sums"])
+        np.testing.assert_allclose(
+            list(a["sums"].values()), list(b["sums"].values()), rtol=1e-9
+        )
+    assert total == 900
+
+
 # ---------------------------------------------------------------------------
 # interpreted ↔ compiled state coherence
 # ---------------------------------------------------------------------------
@@ -261,6 +423,29 @@ def test_shard_map_single_device_parity():
     sharded._jit.sync_store()
     for kg in range(24):
         assert plain.store.get(kg) == sharded.store.get(kg)
+    # Keyed tables take the key-group-sharded path: a first batch whose new
+    # keys overflow the 64-slot tables runs again on grown ones, alike.
+    kw = dict(service_rate=1e9, seed=0, collect_sinks=True)
+    engines = [
+        Engine(real_job_2(keygroups_per_op=4), 2,
+               config=ExecutionConfig.jit(mesh=m), **kw)
+        for m in (None, mesh)
+    ]
+    stream = airline_stream(StreamSpec(rate=400.0, seed=9))
+    batches = [next(stream) for _ in range(3)]
+    for eng in engines:
+        for k, v, ts in batches:
+            eng.push_source("airline", k, v, ts)
+            eng.tick()
+        for _ in range(4):
+            eng.tick()
+        eng._jit.sync_store()
+    a, b = engines
+    assert a.metrics.table_retries >= 1
+    assert b.metrics.table_retries == a.metrics.table_retries
+    assert a.metrics.sink_outputs == b.metrics.sink_outputs
+    for kg in range(a.topology.num_keygroups):
+        assert a.store.get(kg) == b.store.get(kg)
 
 
 SHARDED_PARITY = textwrap.dedent(
@@ -360,6 +545,35 @@ SHARDED_PARITY = textwrap.dedent(
         e._jit.sync_store()
         results.append((e.store.get(g + 1), np.asarray(out[0]).tolist()))
     assert results[0] == results[1] == ({"n": 4}, [0, 1, 2, 3]), results
+
+    # A first batch whose new keys overflow a shard's 64-slot table: the
+    # whole call runs again on grown tables, and the result is unchanged.
+    engines = [
+        Engine(real_job_2(keygroups_per_op=4), 2,
+               config=ExecutionConfig.jit(mesh=m), **kw)
+        for m in (None, mesh)
+    ]
+    stream = airline_stream(StreamSpec(rate=600.0, seed=6))
+    batches = [next(stream) for _ in range(3)]
+    for eng in engines:
+        for k, v, ts in batches:
+            eng.push_source("airline", k, v, ts)
+            eng.tick()
+        for _ in range(4):
+            eng.tick()
+        eng._jit.sync_store()
+    a, b = engines
+    assert a.metrics.table_retries >= 1 and b.metrics.table_retries >= 1
+    assert len(a.metrics.sink_outputs) == len(b.metrics.sink_outputs)
+    for (k1, v1, t1), (k2, v2, t2) in zip(
+        a.metrics.sink_outputs, b.metrics.sink_outputs
+    ):
+        assert k1 == k2 and t1 == t2
+        np.testing.assert_allclose(v1[1], v2[1], rtol=1e-9, atol=1e-9)
+    for kg in range(a.topology.num_keygroups):
+        sa, sb = a.store.get(kg), b.store.get(kg)
+        for name in sa:
+            assert list(sa[name]) == list(sb[name])
     print("SHARDED-PARITY-OK")
     """
 )

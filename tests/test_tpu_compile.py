@@ -105,16 +105,18 @@ def test_bucket_argsort_compiles_inside_shard_map(topo):
     assert _holds_kernel(jax.jit(sort).lower(x))
 
 
-def test_keyed_running_sum_compiles_one_bucket(one_chip, monkeypatch):
+@pytest.mark.parametrize("cap", [2 * EVENTS, 16_384])
+def test_keyed_running_sum_compiles_one_bucket(one_chip, monkeypatch, cap):
     """One padding bucket of the jit tier's keyed running sum, float64, with
-    the TPU's radix argsort — the shape Jobs 2 and 3 compile per bucket."""
+    the TPU's radix argsort — the shape Jobs 2 and 3 compile per bucket,
+    against a table sized for a whole batch (2 × EVENTS) and one sized to
+    twice its 5,000 keys (16,384)."""
     from repro.engine import jitexec as jx
     from repro.kernels.radix_sort import ops as radix_ops
 
     # The sort dispatches on the backend, which is the CPU here: steer it
     # to the TPU branch for this compile only.
     monkeypatch.setattr(radix_ops.jax, "default_backend", lambda: "tpu")
-    cap = 2 * EVENTS
     s = functools.partial(_spec, one_chip)
     table = jx.TableState(
         s((cap,), jnp.int64),

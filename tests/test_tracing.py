@@ -129,8 +129,10 @@ def test_device_fetches_of_one_sumdelay_call():
     (codes, vals, seq, owner and the epoch: 5 reads), then reads the table's
     entry count (1), the output keys and times (2) and the two value
     columns, plane and sum (2); a second call on the same key groups reads
-    only its results (5); a call that outgrows the table reads its codes
-    once more (1) to pad them on the host."""
+    only its results (5); a call whose new keys overflow the table reads
+    its count (1), the codes to grow the table on the host (1), then the
+    re-run's results (5); a table holding more than half its slots is grown
+    before the next call (1)."""
     from repro.engine.jitexec import JitRuntime
 
     topo = real_job_2(keygroups_per_op=4)
@@ -139,9 +141,9 @@ def test_device_fetches_of_one_sumdelay_call():
     op = topo._resolve("sumdelay")
     base = topo.kg_base(op)
 
-    def call(n):
+    def call(n, planes=24):
         values = np.zeros(n, dtype=EXTRACT_SCHEMA.value)
-        values["plane"] = np.arange(n) % 24
+        values["plane"] = np.arange(n) % planes
         values["year"] = 2004
         values["delay"] = 1.0
         half = n // 2
@@ -153,10 +155,16 @@ def test_device_fetches_of_one_sumdelay_call():
     assert call(8) == 5 + 1 + 2 + 2
     assert (metrics.table_entries, metrics.table_capacity) == (8, 64)
     assert call(8) == 5
+    assert call(64) == 5
     assert metrics.table_grows == 0
-    assert call(64) == 1 + 5
-    assert metrics.table_grows == 1
-    assert (metrics.table_entries, metrics.table_capacity) == (24, 128)
+    assert (metrics.table_entries, metrics.table_capacity) == (24, 64)
+    assert call(100, planes=100) == 1 + 1 + 5
+    assert (metrics.table_grows, metrics.table_retries) == (1, 1)
+    assert (metrics.table_entries, metrics.table_capacity) == (100, 256)
+    assert call(150, planes=150) == 5
+    assert call(8) == 1 + 5
+    assert (metrics.table_grows, metrics.table_retries) == (2, 1)
+    assert (metrics.table_entries, metrics.table_capacity) == (150, 512)
 
 
 def test_device_fetches_of_one_routed_batch():
