@@ -696,8 +696,8 @@ def _sum_delay_jit(state, kgs, starts, ends, keys, values, ts):
     codes = (planes << jnp.int64(32)) | years
     kg = kgs[jx.run_of_tuples(ends, nb)]
     valid = jx.tuple_valid(starts, ends, nb)
-    table, running = jx.keyed_running_sum(
-        state["sums"], codes, kg, delays, valid
+    table, (running,) = jx.keyed_running_agg(
+        state["sums"], codes, kg, (delays,), valid, ("sum",)
     )
     return {"sums": table}, (planes, {"plane": planes, "sum": running}, ts), None
 
@@ -713,8 +713,8 @@ def _route_delay_jit(state, kgs, starts, ends, keys, values, ts):
     codes = origins * jnp.int64(na) + dests
     kg = kgs[jx.run_of_tuples(ends, nb)]
     valid = jx.tuple_valid(starts, ends, nb)
-    table, running = jx.keyed_running_sum(
-        state["route_sums"], codes, kg, delays, valid
+    table, (running,) = jx.keyed_running_agg(
+        state["route_sums"], codes, kg, (delays,), valid, ("sum",)
     )
     out = {"origin": origins, "dest": dests, "sum": running, "delay": delays}
     return {"route_sums": table}, (codes, out, ts), None

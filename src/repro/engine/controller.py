@@ -47,15 +47,17 @@ class PeriodMetrics:
     num_unsplits: int = 0
     #: Worker recoveries (supervised respawn + rewind) completed this period.
     num_recoveries: int = 0
-    #: Blocking device→host reads, keyed-table capacity bumps and jit calls
-    #: re-run after a table overflow this period (EngineMetrics.
-    #: device_fetches, table_grows, table_retries), and the keyed device
-    #: tables' entries and slots at its end.
+    #: Blocking device→host reads, keyed-table capacity bumps, jit calls
+    #: re-run after a table overflow and the host seconds capacity changes
+    #: cost this period (EngineMetrics.device_fetches, table_grows,
+    #: table_retries, table_grow_s), and the keyed device tables' entries
+    #: and slots at its end.
     device_fetches: int = 0
     table_grows: int = 0
     table_retries: int = 0
     table_entries: int = 0
     table_capacity: int = 0
+    table_grow_s: float = 0.0
 
 
 class Controller:
@@ -89,6 +91,7 @@ class Controller:
         fetches_before = counters.device_fetches
         grows_before = counters.table_grows
         retries_before = counters.table_retries
+        grow_s_before = counters.table_grow_s
         self.run_ticks(self.config.ticks_per_period)
         snapshot = self.engine.end_period()
 
@@ -171,6 +174,7 @@ class Controller:
             table_retries=counters.table_retries - retries_before,
             table_entries=counters.table_entries,
             table_capacity=counters.table_capacity,
+            table_grow_s=counters.table_grow_s - grow_s_before,
         )
         self.engine.latency.reset()
         self.history.append(metrics)

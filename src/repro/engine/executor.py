@@ -175,6 +175,11 @@ class EngineMetrics:
     table_capacity: int = 0
     table_grows: int = 0
     table_retries: int = 0
+    # Host seconds spent because a table changed capacity: the bumps
+    # themselves with any compile ahead they start, the dispatch of each
+    # shape's first launch at a new capacity (with its compile or cache
+    # load) and the whole relaunches after overflows.
+    table_grow_s: float = 0.0
     # Ticks executed as fused device programs: one per fused superstep tick
     # that reached the device, K per run_supersteps(K) scan (0 whenever the
     # superstep runtime fell back to the classic tick).

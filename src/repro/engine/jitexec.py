@@ -35,7 +35,7 @@ comparison see exactly the dict the per-run oracle would have produced.
 Float-tolerance policy: integer columns, single float operations and the
 first two addends of every running sum are bit-exact on the CPU;
 *multi-term float reductions* (the segmented scan inside
-:func:`keyed_running_sum`) may diverge from the oracle's strict
+:func:`keyed_running_agg`) may diverge from the oracle's strict
 left-to-right association in the last bits — the conformance harness
 compares the jit configuration with a documented ``rtol=1e-9`` on floats
 for exactly this reason (see tests/conformance.py and
@@ -46,7 +46,9 @@ float is held to the tolerance (``chip_smoke.py``); integers stay exact.
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import itertools
 import time
 import weakref
 from typing import NamedTuple, Optional
@@ -77,8 +79,9 @@ EMPTY_CODE = np.iinfo(np.int64).max
 _MIN_TUPLE_BUCKET = 16
 _MIN_RUN_BUCKET = 4
 _MIN_TABLE_CAP = 64
+_NO_SPAN = contextlib.nullcontext()
 # Device arrays read back to materialise one state field on the host.
-_FIELD_FETCHES = {"scalar": 1, "vector": 2, "table": 4}
+_FIELD_FETCHES = {"scalar": 1, "vector": 2}
 
 
 def _bucket(x: int, lo: int) -> int:
@@ -99,18 +102,18 @@ class TableState(NamedTuple):
     Entries of *all* key groups share one capacity-``S`` slab (codes are
     globally unique — a code determines its key group — so no per-key-group
     partitioning is needed): ``codes``/``vals``/``owner`` hold the entries
-    in insertion order (``cnt`` used, :data:`EMPTY_CODE` beyond), ``seq``
-    carries ``epoch << 32 | first_position`` — monotone in insertion order
-    across calls, which is what reproduces the oracle dicts' insertion
-    order without ranking new entries per key group — and ``perm`` is the
-    code-sorted permutation of the slab, maintained *incrementally* by the
-    merge in :func:`keyed_running_sum` (new codes arrive pre-sorted from
-    the segment sort, so keeping the view sorted costs searchsorted +
-    prefix sums, never a table-sized sort).
+    in insertion order (``cnt`` used, :data:`EMPTY_CODE` beyond; ``vals``
+    is a tuple of arrays, one per value column), ``seq`` carries
+    ``epoch << 32 | first_position`` — monotone in insertion order across
+    calls, which is what reproduces the oracle dicts' insertion order
+    without ranking new entries per key group — and ``perm`` is the code-sorted permutation of the slab, maintained
+    *incrementally* by the merge in :func:`keyed_running_agg` (new codes
+    arrive pre-sorted from the segment sort, so keeping the view sorted
+    costs searchsorted + prefix sums, never a table-sized sort).
     """
 
     codes: jax.Array  # (S,) int64, insertion order
-    vals: jax.Array  # (S,) value dtype
+    vals: tuple  # (S,) value dtype per column
     seq: jax.Array  # (S,) int64: epoch << 32 | first position
     owner: jax.Array  # (S,) int32 key group of each entry
     perm: jax.Array  # (S,) int32: slab indices in code-sorted order
@@ -168,50 +171,67 @@ def count_runs(col: jax.Array, kgs, starts, ends) -> jax.Array:
     )
 
 
-def segmented_prefix_sum(x: jax.Array, head: jax.Array) -> jax.Array:
-    """Inclusive prefix sums of ``x`` that restart at every ``head``.
+#: Each table combine as an elementwise operation.
+_COMBINE = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
 
-    A log-depth parallel scan: ``jnp.cumsum`` lowers to a window reduction
-    that XLA:TPU compiles for minutes on emulated float64, this compiles in
-    seconds for every dtype.  Each sum takes only its own segment's terms,
-    so float error scales with the segment's magnitude, not the batch's;
-    the association differs from a left-to-right fold only in the last
-    bits, and not at all for a segment's first two terms.
+
+def segmented_scan(xs: tuple, head: jax.Array, combines: tuple) -> tuple:
+    """Inclusive scans of the columns ``xs`` that restart at every ``head``,
+    column ``j`` under its combine ``combines[j]`` (``"sum"``, ``"min"`` or
+    ``"max"``).
+
+    One log-depth parallel scan for all columns: ``jnp.cumsum`` lowers to
+    a window reduction that XLA:TPU compiles for minutes on emulated
+    float64, this compiles in seconds for every dtype.  Each value takes
+    only its own segment's terms, so float error scales with the segment's
+    magnitude, not the batch's; a sum's association differs from a
+    left-to-right fold only in the last bits, and not at all for a
+    segment's first two terms.  Integer columns, and min and max of any
+    dtype, are exact.
     """
+    ops = tuple(_COMBINE[c] for c in combines)
 
     def combine(a, b):
         ha, xa = a
         hb, xb = b
-        return ha | hb, jnp.where(hb, xb, xa + xb)
+        return ha | hb, tuple(
+            jnp.where(hb, y, op(x, y)) for op, x, y in zip(ops, xa, xb)
+        )
 
-    return jax.lax.associative_scan(combine, (head, x))[1]
+    return jax.lax.associative_scan(combine, (head, tuple(xs)))[1]
 
 
-def keyed_running_sum(
+def keyed_running_agg(
     table: TableState,
     codes: jax.Array,
     kg: jax.Array,
-    addends: jax.Array,
+    values: tuple,
     valid: jax.Array,
+    combines: tuple,
     order: Optional[jax.Array] = None,
-) -> tuple[TableState, jax.Array]:
-    """Grouped running sums over one segment, against the keyed table.
+) -> tuple[TableState, tuple]:
+    """Grouped running aggregates over one segment, against the keyed table.
 
-    For every tuple ``i``: looks up ``codes[i]`` in the flat table, adds the
-    within-segment prefix of its group's ``addends`` and returns the
-    per-tuple running totals; new codes are appended to the slab with
-    ``seq = epoch << 32 | first_position`` — monotone in first-occurrence
-    order, which is exactly the order the per-run oracle inserts them into
-    its dicts.  Requirements: equal codes always map to the same key group
-    (key the table by the operator's partition key), and real codes are
-    non-negative and < 2^63 − 1.
+    ``table.vals`` holds one column per entry of ``combines`` (a tuple of
+    arrays); ``values[j]`` is each tuple's term for column ``j``.  For every
+    tuple ``i``: looks up ``codes[i]`` in the flat table, combines its
+    group's terms up to ``i`` into the table's values and returns, per
+    column, the per-tuple running value; new codes are appended to the slab
+    with ``seq = epoch << 32 | first_position`` — monotone in
+    first-occurrence order, which is exactly the order the per-run oracle
+    inserts them into its dicts.  Requirements: equal codes always map to
+    the same key group (key the table by the operator's partition key), and
+    real codes are non-negative and < 2^63 − 1.
 
-    Cost: ONE stable sort of the segment (:func:`radix_argsort_jax`; the
-    table's code-sorted view is maintained incrementally by merging the
-    segment's pre-sorted new codes: searchsorted + prefix sums), plus
-    O(segment + capacity) gathers/scatters.  The within-group running sums
-    are one :func:`segmented_prefix_sum` seeded with each group's table
-    value — the one place the jit tier's floats may diverge from the
+    Cost, whatever the number of columns: ONE stable sort of the segment
+    (:func:`radix_argsort_jax`), ONE lookup through the table's code-sorted
+    view, and ONE merge of the segment's pre-sorted new codes into that
+    view (searchsorted + prefix sums, never a table-sized sort); per column
+    one gather of the table values, one :func:`segmented_scan` lane and two
+    scatters.  A ``"sum"`` column's group is seeded ``base + first term``
+    (``0 + term`` for a new key, as the oracle's ``get(key, 0) + term``);
+    a ``"min"``/``"max"`` column's only where the key is in the table.
+    Float sums are the one place the jit tier may diverge from the
     oracle's left-to-right association (module docstring's tolerance
     policy); a group's first two sums associate exactly as the oracle's,
     so singleton groups (mostly-unique keys) stay bit-exact end to end.
@@ -238,17 +258,25 @@ def keyed_running_sum(
     sc = mcodes[order]
     real = sc != EMPTY_CODE
     sk = jnp.where(real, kg[order], 0)
-    sa = jnp.where(real, addends[order], jnp.zeros((), addends.dtype))
     head = jnp.concatenate([jnp.ones(1, bool), sc[1:] != sc[:-1]])
     # Lookup through the maintained code-sorted view.
     scodes = table.codes[table.perm]  # (cap,) sorted, EMPTY tail
     pos = jnp.minimum(jnp.searchsorted(scodes, sc), cap - 1)
     fidx = table.perm[pos].astype(jnp.int64)  # candidate slab index
     has = (scodes[pos] == sc) & real
-    base = jnp.where(has, table.vals[fidx], jnp.zeros((), table.vals.dtype))
-    # Within-group running sums, each group seeded with its table value.
-    running_sorted = segmented_prefix_sum(jnp.where(head, base + sa, sa), head)
-    running = jnp.zeros(nb, running_sorted.dtype).at[order].set(running_sorted)
+    # Within-group running values, each group seeded with its table value.
+    seeded = []
+    for col, x, c in zip(table.vals, values, combines):
+        sa = jnp.where(real, x[order], jnp.zeros((), x.dtype))
+        base = jnp.where(has, col[fidx], jnp.zeros((), col.dtype))
+        if c == "sum":
+            seeded.append(jnp.where(head, base + sa, sa))
+        else:
+            seeded.append(jnp.where(head & has, _COMBINE[c](base, sa), sa))
+    running_sorted = segmented_scan(seeded, head, combines)
+    running = tuple(
+        jnp.zeros(nb, r.dtype).at[order].set(r) for r in running_sorted
+    )
     # Integer prefix counts run in int32 (they are bounded by the segment
     # and table sizes): XLA:TPU compiles int64 scans several times slower.
     seg = jnp.cumsum(head, dtype=jnp.int32) - 1  # group per sorted position
@@ -271,7 +299,10 @@ def keyed_running_sum(
     wvalid = tail & real
     widx = jnp.where(wvalid, widx_all, cap)  # out of range → dropped
     codes2 = table.codes.at[widx].set(sc, mode="drop")
-    vals2 = table.vals.at[widx].set(running_sorted, mode="drop")
+    vals2 = tuple(
+        col.at[widx].set(r, mode="drop")
+        for col, r in zip(table.vals, running_sorted)
+    )
     # seq/owner only change for new entries (scatter at newheads).
     nidx = jnp.where(newhead, dest, cap)
     seq_new = (table.epoch << jnp.int64(32)) | order
@@ -283,8 +314,10 @@ def keyed_running_sum(
     ncex = jnp.concatenate([jnp.zeros(1, jnp.int64), nc_in])  # exclusive
     is_empty_old = scodes == EMPTY_CODE
     shift_real = ncex[jnp.searchsorted(sc, scodes, side="left")]
-    jemp = jnp.cumsum(is_empty_old, dtype=jnp.int32) - 1
     arange_cap = jnp.arange(cap)
+    # The EMPTY entries are the view's tail, after its ``cnt`` real codes:
+    # their rank among the EMPTY pointers is their position less ``cnt``.
+    jemp = arange_cap - table.cnt
     oldpos = jnp.where(
         is_empty_old,
         jnp.where(jemp < total_new, cap, arange_cap),  # consumed → dropped
@@ -455,7 +488,7 @@ def _jitted_sharded_tables(fn, mesh, axis, table_names):
                 nb = keys_r.shape[0]
                 st_loc = {
                     name: (
-                        TableState(*(x[0] for x in v))
+                        jax.tree_util.tree_map(lambda x: x[0], v)
                         if name in table_names
                         else v
                     )
@@ -474,7 +507,9 @@ def _jitted_sharded_tables(fn, mesh, axis, table_names):
                 out_state = {}
                 for name, new in state2.items():
                     if name in table_names:
-                        out_state[name] = TableState(*(x[None] for x in new))
+                        out_state[name] = jax.tree_util.tree_map(
+                            lambda x: x[None], new
+                        )
                         continue
                     orig = state_in[name]
                     leaves_o = jax.tree_util.tree_leaves(orig)
@@ -546,6 +581,8 @@ class _OpState:
         "out_dtype",
         "out_names",
         "seen_keys",
+        "shapes",
+        "compiled",
     )
 
     def __init__(self, op: int, spec, base: int, shards: int = 0) -> None:
@@ -579,11 +616,11 @@ class _OpState:
                 if self.shards:
                     self.cnt_host[f.name] = np.zeros(self.shards, np.int64)
                     cols[f.name] = _stack_tables(
-                        [_empty_table(cap, f.dtype)] * self.shards
+                        [_empty_table(cap, f)] * self.shards
                     )
                 else:
                     self.cnt_host[f.name] = 0
-                    cols[f.name] = _empty_table(cap, f.dtype)
+                    cols[f.name] = _empty_table(cap, f)
         self.cols = cols
         self.value_names = (
             spec.schema.value.names if spec.schema is not None else None
@@ -593,17 +630,21 @@ class _OpState:
         self.out_names = (
             None if out_schema is None else out_schema.value.names
         )
-        self.seen_keys: set = set()
+        self.seen_keys: set = set()  # (shape, capacities) launched
+        # Each shape launched, at any capacity: its jitted function and its
+        # arguments' abstract values (what a compile ahead lowers).
+        self.shapes: dict = {}
+        self.compiled: dict = {}  # (shape, capacities) compiled ahead
 
     def shard_of(self, lkgs: np.ndarray) -> np.ndarray:
         """Owning shard per local key group (monotone in the key group)."""
         return (np.asarray(lkgs, dtype=np.int64) * self.shards) // self.nkg
 
 
-def _empty_table(cap: int, dtype) -> TableState:
+def _empty_table(cap: int, f: StateField) -> TableState:
     return TableState(
         codes=jnp.full(cap, EMPTY_CODE, dtype=jnp.int64),
-        vals=jnp.zeros(cap, dtype=dtype),
+        vals=tuple(jnp.zeros(cap, dtype=f.dtype) for _ in f.combines),
         seq=jnp.zeros(cap, dtype=jnp.int64),
         owner=jnp.zeros(cap, dtype=jnp.int32),
         perm=jnp.arange(cap, dtype=jnp.int32),
@@ -614,9 +655,27 @@ def _empty_table(cap: int, dtype) -> TableState:
 
 def _stack_tables(tables: list[TableState]) -> TableState:
     """Stack per-shard sub-tables along a new leading shard axis."""
-    return TableState(
-        *(jnp.stack(leaf) for leaf in zip(*tables))
-    )
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *tables)
+
+
+def _host_vals(vals) -> np.ndarray:
+    """A table's value columns on the host, stacked on a leading axis."""
+    return np.stack([np.asarray(c) for c in vals])
+
+
+def _dict_entries(store, ost: _OpState, f: StateField, lkgs) -> tuple:
+    """The dict entries of key groups ``lkgs``, key group by key group in
+    insertion order: their codes, their values as a (columns, entries)
+    array and their owning key groups."""
+    codes, rows, owners = [], [], []
+    enc = f.key_encode
+    for lk in lkgs:
+        for key, val in store[ost.base + int(lk)].get(f.name, {}).items():
+            codes.append(enc(key))
+            rows.append(val)
+            owners.append(lk)
+    vals = np.asarray(rows, dtype=f.dtype).reshape(len(rows), len(f.combines))
+    return codes, vals.T, owners
 
 
 class JitRuntime:
@@ -764,17 +823,22 @@ class JitRuntime:
         return (ok_np, ov_np, ot_np), lens
 
     def _launch(self, ost: _OpState, key, jitted, args):
-        """Dispatch one jitted call; a new ``key`` compiles, and its call
-        waits for the device so the compile is timed."""
+        """Dispatch one jitted call: a program compiled ahead for ``key``
+        runs as it is; a new ``key`` compiles (or loads from the cache)
+        inside the dispatch, which is timed without waiting for the
+        device."""
         with span("jit.launch"):
-            if key in ost.seen_keys:
-                return jitted(ost.cols, *args)
+            first = key not in ost.seen_keys
             ost.seen_keys.add(key)
+            program = ost.compiled.get(key)
+            if program is not None:
+                return program(ost.cols, *args)
+            if not first:
+                return jitted(ost.cols, *args)
             self._metrics.jit_compiles += 1
             with span("jit.compile"):
                 t0 = time.perf_counter()
                 result = jitted(ost.cols, *args)
-                jax.block_until_ready(result)
                 self.compile_seconds += time.perf_counter() - t0
             return result
 
@@ -782,15 +846,36 @@ class JitRuntime:
         """Launch one call and read its tables' counts back.  A call whose
         new keys overflowed a table left the state as it was: put the
         counts back, grow the table to twice what it would have held and
-        launch the same arguments again (the first outputs are dropped)."""
+        launch the same arguments again (the first outputs are dropped).
+
+        ``table_grow_s`` takes the time a capacity change costs here: a
+        whole relaunch after an overflow, and the dispatch of a shape's
+        first launch at a capacity it has not run at (with its compile or
+        cache load, unless compiled ahead); ``_grow`` adds its own."""
+        retry = False
         while True:
-            key = shape_key + (tuple(sorted(ost.caps.items())),)
-            result = self._launch(ost, key, jitted, args)
-            ost.cols = result[0]
-            with span("jit.fetch"):
-                over = self._fetch_counts(ost, result[0])
+            caps = tuple(sorted(ost.caps.items()))
+            new_cap = (
+                (shape_key, caps) not in ost.seen_keys
+                and shape_key in ost.shapes
+            )
+            t0 = time.perf_counter()
+            with span("jit.retry") if retry else _NO_SPAN:
+                result = self._launch(ost, (shape_key, caps), jitted, args)
+                if new_cap and not retry:
+                    self._metrics.table_grow_s += time.perf_counter() - t0
+                ost.cols = result[0]
+                with span("jit.fetch"):
+                    over = self._fetch_counts(ost, result[0])
+            if retry:
+                self._metrics.table_grow_s += time.perf_counter() - t0
+            if shape_key not in ost.shapes:
+                ost.shapes[shape_key] = (jitted, jax.tree_util.tree_map(
+                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args
+                ))
             if not over:
                 return result
+            retry = True
             self._metrics.table_retries += 1
             self._metrics.jit_host_syncs += 1
             for f in ost.fields:
@@ -870,7 +955,7 @@ class JitRuntime:
         key group, run order and within-run tuple order are preserved
         (stable sort; a key group lives wholly on one shard), and equal
         codes share a key group — so the per-code tie-break order inside
-        :func:`keyed_running_sum` is unchanged and the result is
+        :func:`keyed_running_agg` is unchanged and the result is
         bit-identical to the plain call, modulo the documented prefix-sum
         float policy.  Outputs come back positionally over the permuted
         tuples and are ungathered on the host (1:1 bodies only).
@@ -967,47 +1052,94 @@ class JitRuntime:
 
     @traced("jit.grow")
     def _grow(self, ost: _OpState, f: StateField, entries: int) -> None:
-        """Extend the slab to twice ``entries``; the sorted view's EMPTY
+        """Extend the slab to twice ``entries``.  Every slab leaf is padded
+        on the host and put back, so no device program runs at the new
+        shape and a doubling compiles nothing here; the sorted view's EMPTY
         tail (ascending by slab index) extends with the fresh indices — no
         re-sort needed."""
+        t0 = time.perf_counter()
         new_cap = _table_cap(entries)
         t = ost.cols[f.name]
         old = ost.caps[f.name]
-        pad = new_cap - old
         self._metrics.table_grows += 1
-        self._metrics.device_fetches += 1  # the codes, padded on the host
-        if ost.shards:
-            d = ost.shards
-            codes = np.full((d, new_cap), EMPTY_CODE, dtype=np.int64)
-            codes[:, :old] = np.asarray(t.codes)
-            tail = jnp.broadcast_to(
-                jnp.arange(old, new_cap, dtype=t.perm.dtype), (d, pad)
-            )
-            ost.cols[f.name] = TableState(
-                codes=jnp.asarray(codes),
-                vals=jnp.pad(t.vals, ((0, 0), (0, pad))),
-                seq=jnp.pad(t.seq, ((0, 0), (0, pad))),
-                owner=jnp.pad(t.owner, ((0, 0), (0, pad))),
-                perm=jnp.concatenate([t.perm, tail], axis=1),
-                cnt=t.cnt,
-                epoch=t.epoch,
-            )
-            ost.caps[f.name] = new_cap
-            return
-        codes = np.full(new_cap, EMPTY_CODE, dtype=np.int64)
-        codes[:old] = np.asarray(t.codes)
+        # codes, seq, owner, perm and each value column
+        self._metrics.device_fetches += 4 + len(f.combines)
+
+        def grown(x, tail):
+            h = np.asarray(x)
+            out = np.empty(h.shape[:-1] + (new_cap,), dtype=h.dtype)
+            out[..., :old] = h
+            out[..., old:] = tail
+            return jax.device_put(out, x.sharding) if ost.shards else jnp.asarray(out)
+
         ost.cols[f.name] = TableState(
-            codes=jnp.asarray(codes),
-            vals=jnp.pad(t.vals, (0, pad)),
-            seq=jnp.pad(t.seq, (0, pad)),
-            owner=jnp.pad(t.owner, (0, pad)),
-            perm=jnp.concatenate(
-                [t.perm, jnp.arange(old, new_cap, dtype=t.perm.dtype)]
-            ),
+            codes=grown(t.codes, EMPTY_CODE),
+            vals=jax.tree_util.tree_map(lambda x: grown(x, 0), t.vals),
+            seq=grown(t.seq, 0),
+            owner=grown(t.owner, 0),
+            perm=grown(t.perm, np.arange(old, new_cap)),
             cnt=t.cnt,
             epoch=t.epoch,
         )
         ost.caps[f.name] = new_cap
+        self._compile_ahead(ost)
+        self._metrics.table_grow_s += time.perf_counter() - t0
+
+    def _compile_ahead(self, ost: _OpState) -> None:
+        """Compile every shape launched so far at each capacity its tables
+        may grow into, up to twice their fields' ``expected_entries``:
+        a table growing there later runs a program compiled here and
+        compiles nothing on the stream's path (on a TPU a large table's
+        program compiles for tens of seconds)."""
+        steps = []
+        for f in ost.fields:
+            if f.kind != "table":
+                continue
+            cap = ost.caps[f.name]
+            top = max(cap, _table_cap(f.expected_entries))
+            steps.append(
+                [(f.name, cap << i) for i in range((top // cap).bit_length())]
+            )
+        if all(len(s) == 1 for s in steps):
+            return
+        for caps in itertools.product(*steps):
+            caps = tuple(sorted(caps))
+            state = self._state_spec(ost, dict(caps))
+            for shape_key, (jitted, args) in ost.shapes.items():
+                key = (shape_key, caps)
+                if key in ost.seen_keys or key in ost.compiled:
+                    continue
+                self._metrics.jit_compiles += 1
+                with span("jit.compile"):
+                    t0 = time.perf_counter()
+                    ost.compiled[key] = jitted.lower(state, *args).compile()
+                    self.compile_seconds += time.perf_counter() - t0
+
+    @staticmethod
+    def _state_spec(ost: _OpState, caps: dict) -> dict:
+        """The abstract values of the operator's state with its tables at
+        ``caps``."""
+
+        def spec(x, cap=None):
+            shape = x.shape if cap is None else x.shape[:-1] + (cap,)
+            return jax.ShapeDtypeStruct(shape, x.dtype, sharding=x.sharding)
+
+        out = {}
+        for name, col in ost.cols.items():
+            if name in caps:
+                cap = caps[name]
+                out[name] = TableState(
+                    codes=spec(col.codes, cap),
+                    vals=tuple(spec(v, cap) for v in col.vals),
+                    seq=spec(col.seq, cap),
+                    owner=spec(col.owner, cap),
+                    perm=spec(col.perm, cap),
+                    cnt=spec(col.cnt),
+                    epoch=spec(col.epoch),
+                )
+            else:
+                out[name] = jax.tree_util.tree_map(spec, col)
+        return out
 
     def _push(self, ost: _OpState, pend: np.ndarray) -> None:
         """Rebuild the columns with the pushed key groups' dict state.
@@ -1054,19 +1186,13 @@ class JitRuntime:
             t = ost.cols[f.name]
             cnt = ost.cnt_host[f.name]
             codes = np.asarray(t.codes)[:cnt]
-            vals = np.asarray(t.vals)[:cnt]
+            vals = _host_vals(t.vals)[:, :cnt]
             seq = np.asarray(t.seq)[:cnt]
             owner = np.asarray(t.owner)[:cnt]
-            self._metrics.device_fetches += 5  # four columns and the epoch
+            # codes, seq, owner, each value column and the epoch
+            self._metrics.device_fetches += 4 + len(f.combines)
             keep = ~np.isin(owner, pend)
-            new_c, new_v, new_o = [], [], []
-            enc = f.key_encode
-            for lk in pend:
-                d = store[ost.base + int(lk)].get(f.name, {})
-                for key, val in d.items():
-                    new_c.append(enc(key))
-                    new_v.append(val)
-                    new_o.append(lk)
+            new_c, new_v, new_o = _dict_entries(store, ost, f, pend)
             n_keep = int(keep.sum())
             total = n_keep + len(new_c)
             cap = ost.caps[f.name]
@@ -1074,24 +1200,24 @@ class JitRuntime:
                 cap = ost.caps[f.name] = _table_cap(total)
                 self._metrics.table_grows += 1
             pc = np.full(cap, EMPTY_CODE, dtype=np.int64)
-            pv = np.zeros(cap, dtype=f.dtype)
+            pv = np.zeros((len(f.combines), cap), dtype=f.dtype)
             ps = np.zeros(cap, dtype=np.int64)
             po = np.zeros(cap, dtype=np.int32)
             pc[:n_keep] = codes[keep]
-            pv[:n_keep] = vals[keep]
+            pv[:, :n_keep] = vals[:, keep]
             ps[:n_keep] = seq[keep]
             po[:n_keep] = owner[keep]
             base_seq = int(ps[:n_keep].max()) + 1 if n_keep else 0
             if new_c:
                 pc[n_keep:total] = new_c
-                pv[n_keep:total] = new_v
+                pv[:, n_keep:total] = new_v
                 ps[n_keep:total] = base_seq + np.arange(len(new_c))
                 po[n_keep:total] = new_o
             max_seq = int(ps[:total].max()) if total else 0
             epoch = max(int(t.epoch), (max_seq >> 32) + 1)
             ost.cols[f.name] = TableState(
                 codes=jnp.asarray(pc),
-                vals=jnp.asarray(pv),
+                vals=tuple(jnp.asarray(v) for v in pv),
                 seq=jnp.asarray(ps),
                 owner=jnp.asarray(po),
                 perm=jnp.asarray(
@@ -1114,28 +1240,22 @@ class JitRuntime:
         t = ost.cols[f.name]
         cnt_arr = np.asarray(ost.cnt_host[f.name], dtype=np.int64).copy()
         codes_h = np.asarray(t.codes)
-        vals_h = np.asarray(t.vals)
+        vals_h = _host_vals(t.vals)  # (columns, shards, capacity)
         seq_h = np.asarray(t.seq)
         owner_h = np.asarray(t.owner)
         perm_h = np.asarray(t.perm)
         epoch_h = np.asarray(t.epoch).copy()
-        self._metrics.device_fetches += 6
+        # codes, seq, owner, perm, each value column and the epoch
+        self._metrics.device_fetches += 5 + len(f.combines)
         shard_ids = ost.shard_of(pend)
         old_cap = codes_h.shape[1]
         cap = ost.caps[f.name]
-        enc = f.key_encode
         per_shard = {}
         for s in sorted(set(shard_ids.tolist())):
             kgs_s = pend[shard_ids == s]
             cnt = int(cnt_arr[s])
             keep = ~np.isin(owner_h[s, :cnt], kgs_s)
-            new_c, new_v, new_o = [], [], []
-            for lk in kgs_s:
-                dct = store[ost.base + int(lk)].get(f.name, {})
-                for key, val in dct.items():
-                    new_c.append(enc(key))
-                    new_v.append(val)
-                    new_o.append(lk)
+            new_c, new_v, new_o = _dict_entries(store, ost, f, kgs_s)
             total = int(keep.sum()) + len(new_c)
             per_shard[s] = (cnt, keep, new_c, new_v, new_o, total)
             cap = max(cap, _table_cap(total))
@@ -1143,14 +1263,14 @@ class JitRuntime:
             self._metrics.table_grows += 1
         ost.caps[f.name] = cap
         pc = np.full((d, cap), EMPTY_CODE, dtype=np.int64)
-        pv = np.zeros((d, cap), dtype=f.dtype)
+        pv = np.zeros((len(f.combines), d, cap), dtype=f.dtype)
         ps = np.zeros((d, cap), dtype=np.int64)
         po = np.zeros((d, cap), dtype=np.int32)
         pp = np.zeros((d, cap), dtype=np.int32)
         for s in range(d):
             if s not in per_shard:
                 pc[s, :old_cap] = codes_h[s]
-                pv[s, :old_cap] = vals_h[s]
+                pv[:, s, :old_cap] = vals_h[:, s]
                 ps[s, :old_cap] = seq_h[s]
                 po[s, :old_cap] = owner_h[s]
                 pp[s, :old_cap] = perm_h[s]
@@ -1159,13 +1279,13 @@ class JitRuntime:
             cnt, keep, new_c, new_v, new_o, total = per_shard[s]
             n_keep = int(keep.sum())
             pc[s, :n_keep] = codes_h[s, :cnt][keep]
-            pv[s, :n_keep] = vals_h[s, :cnt][keep]
+            pv[:, s, :n_keep] = vals_h[:, s, :cnt][:, keep]
             ps[s, :n_keep] = seq_h[s, :cnt][keep]
             po[s, :n_keep] = owner_h[s, :cnt][keep]
             base_seq = int(ps[s, :n_keep].max()) + 1 if n_keep else 0
             if new_c:
                 pc[s, n_keep:total] = new_c
-                pv[s, n_keep:total] = new_v
+                pv[:, s, n_keep:total] = new_v
                 ps[s, n_keep:total] = base_seq + np.arange(len(new_c))
                 po[s, n_keep:total] = new_o
             pp[s] = np.argsort(pc[s], kind="stable").astype(np.int32)
@@ -1174,7 +1294,7 @@ class JitRuntime:
             cnt_arr[s] = total
         ost.cols[f.name] = TableState(
             codes=jnp.asarray(pc),
-            vals=jnp.asarray(pv),
+            vals=tuple(jnp.asarray(v) for v in pv),
             seq=jnp.asarray(ps),
             owner=jnp.asarray(po),
             perm=jnp.asarray(pp),
@@ -1195,51 +1315,63 @@ class JitRuntime:
                     f.py(x) for x in data[lk][: int(cnt[lk])]
                 ]
             else:
-                codes, vals, seq, owner = host[f.name]
-                mine = np.flatnonzero(owner == lk)
-                order = mine[np.argsort(seq[mine], kind="stable")]
-                dec = f.key_decode
+                codes, vals, bounds = host[f.name]
+                a, z = bounds[lk], bounds[lk + 1]
+                keys = map(f.key_decode, codes[a:z].tolist())
                 py = f.py
-                d = {}
-                for j in order.tolist():
-                    d[dec(int(codes[j]))] = py(vals[j])
-                out[f.name] = d
+                if isinstance(f.combine, str):  # one column: a scalar
+                    rows = map(py, vals[0, a:z].tolist())
+                else:
+                    rows = (
+                        tuple(map(py, row)) for row in vals[:, a:z].T.tolist()
+                    )
+                out[f.name] = dict(zip(keys, rows))
         return out
 
     def _host_cols(self, ost: _OpState) -> dict:
+        """Every state field on the host.  A table's entries come sorted by
+        key group, then by insertion (``seq``), with each key group's bounds:
+        ``(codes, values (columns, entries), bounds)``."""
         host = {}
         for f in ost.fields:
-            self._metrics.device_fetches += _FIELD_FETCHES[f.kind]
             if f.kind == "scalar":
+                self._metrics.device_fetches += _FIELD_FETCHES[f.kind]
                 host[f.name] = np.asarray(ost.cols[f.name])
-            elif f.kind == "vector":
+                continue
+            if f.kind == "vector":
+                self._metrics.device_fetches += _FIELD_FETCHES[f.kind]
                 v = ost.cols[f.name]
                 host[f.name] = (np.asarray(v.data), np.asarray(v.cnt))
-            elif ost.shards:
+                continue
+            # codes, seq, owner and each value column
+            self._metrics.device_fetches += 3 + len(f.combines)
+            t = ost.cols[f.name]
+            cnt = ost.cnt_host[f.name]
+            codes_h = np.asarray(t.codes)
+            vals_h = _host_vals(t.vals)
+            seq_h = np.asarray(t.seq)
+            owner_h = np.asarray(t.owner)
+            if ost.shards:
                 # Flatten the per-shard slabs into one valid-entry view; a
-                # key group's entries live wholly in its owning shard, so
-                # the per-key-group seq order is preserved.
-                t = ost.cols[f.name]
-                cnt = ost.cnt_host[f.name]
-                codes_h = np.asarray(t.codes)
-                vals_h = np.asarray(t.vals)
-                seq_h = np.asarray(t.seq)
-                owner_h = np.asarray(t.owner)
-                host[f.name] = tuple(
-                    np.concatenate(
-                        [arr[s, : int(cnt[s])] for s in range(ost.shards)]
-                    )
-                    for arr in (codes_h, vals_h, seq_h, owner_h)
+                # key group's entries live wholly in its owning shard.
+                live = [slice(0, int(cnt[s])) for s in range(ost.shards)]
+                codes_h, seq_h, owner_h = (
+                    np.concatenate([arr[s, c] for s, c in enumerate(live)])
+                    for arr in (codes_h, seq_h, owner_h)
+                )
+                vals_h = np.concatenate(
+                    [vals_h[:, s, c] for s, c in enumerate(live)], axis=1
                 )
             else:
-                t = ost.cols[f.name]
-                cnt = ost.cnt_host[f.name]
-                host[f.name] = (
-                    np.asarray(t.codes)[:cnt],
-                    np.asarray(t.vals)[:cnt],
-                    np.asarray(t.seq)[:cnt],
-                    np.asarray(t.owner)[:cnt],
+                codes_h, seq_h, owner_h = (
+                    arr[:cnt] for arr in (codes_h, seq_h, owner_h)
                 )
+                vals_h = vals_h[:, :cnt]
+            order = np.lexsort((seq_h, owner_h))  # stable: slab order on ties
+            bounds = np.searchsorted(
+                owner_h[order], np.arange(ost.nkg + 1)
+            ).tolist()
+            host[f.name] = (codes_h[order], vals_h[:, order], bounds)
         return host
 
     def ensure_dict(self, kg: int) -> None:

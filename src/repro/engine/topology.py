@@ -150,6 +150,10 @@ class Schema:
 JitFn = Callable[..., tuple]
 
 
+#: The associative combines a keyed table's value column may declare.
+TABLE_COMBINES = ("sum", "min", "max")
+
+
 @dataclasses.dataclass(frozen=True)
 class StateField:
     """One declared per-key-group state column of a jit-tier operator.
@@ -159,15 +163,23 @@ class StateField:
     ``{name: py(cell)}``.
 
     ``kind="table"``: a keyed accumulator — per key group a bounded table of
-    ``(int64 code, dtype value)`` entries plus insertion sequence numbers,
-    materialized as ``{name: {key_decode(code): float(value), ...}}`` in
-    insertion order (the order the per-run oracle would have inserted them).
+    ``(int64 code, dtype values)`` entries plus insertion sequence numbers,
+    materialized as ``{name: {key_decode(code): value, ...}}`` in insertion
+    order (the order the per-run oracle would have inserted them).
+    ``combine`` names each value column's associative combine — ``"sum"``,
+    ``"min"`` or ``"max"``: one name (the default ``"sum"``) is one column,
+    materialized as the scalar ``py(v)``; a tuple of names is that many
+    columns, materialized as the tuple ``(py(v1), ..., py(vk))``.
     ``key_encode``/``key_decode`` convert between the oracle's dict keys and
     the int64 codes the device table stores; codes must be unique per dict
     key and — because a table row belongs to one key group — equal codes
     must always hash to the same key group (keying the table by the
     operator's partition key guarantees this).  Capacity is managed by the
     runtime (power-of-two growth; a growth step is a recompile bucket).
+    ``expected_entries`` (0: none) is how many entries the table is expected
+    to reach: once it first grows, the runtime compiles its programs for
+    every capacity up to twice that, so growing into them later compiles
+    nothing on the stream's path.
 
     ``kind="vector"``: a bounded per-key-group ring of ``length`` ``dtype``
     cells plus an occupancy count (sliding windows), materialized as
@@ -183,16 +195,31 @@ class StateField:
     key_encode: Optional[Callable[[object], int]] = None
     key_decode: Optional[Callable[[int], object]] = None
     length: int = 0  # vector kind: bounded window capacity
+    combine: object = "sum"  # table kind: one combine name, or a tuple
+    expected_entries: int = 0  # table kind: entries to compile ahead for
 
     def __post_init__(self) -> None:
         if self.kind not in ("scalar", "table", "vector"):
             raise ValueError(f"unknown StateField kind {self.kind!r}")
+        if not self.combines or any(
+            c not in TABLE_COMBINES for c in self.combines
+        ):
+            raise ValueError(
+                f"field {self.name!r}: combines must be among "
+                f"{TABLE_COMBINES}, got {self.combine!r}"
+            )
         if self.kind == "table" and (
             self.key_encode is None or self.key_decode is None
         ):
             raise ValueError(f"table field {self.name!r} needs key_encode/decode")
         if self.kind == "vector" and self.length <= 0:
             raise ValueError(f"vector field {self.name!r} needs length > 0")
+
+    @property
+    def combines(self) -> tuple:
+        """The table's value columns, one combine name each."""
+        c = self.combine
+        return (c,) if isinstance(c, str) else tuple(c)
 
 
 @dataclasses.dataclass(frozen=True)

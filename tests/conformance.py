@@ -61,7 +61,8 @@ flow must never inherit the tolerance: jit operators' float outputs must
 not feed partition keys, see docs/operator_authoring.md).
 
 This is the required check for new operators, new ``fn_seg`` ports and new
-schema declarations: add a topology + feeder entry to ``JOBS`` (or call
+schema declarations: add a topology + feeder entry to ``JOBS`` (NEXmark q17
+is one: a multi-column keyed table under sum, min and max) (or call
 :func:`run_configs` directly) and assert with :func:`assert_equivalent`.
 See ``tests/test_real_jobs_conformance.py`` for the real-job instantiation
 and ``docs/operator_authoring.md`` for the authoring contract.
@@ -81,6 +82,7 @@ import math
 import numpy as np
 
 from repro.data.jobs import make_real_job_1, real_job_2, real_job_3, real_job_4
+from repro.data.nexmark import bid_stream, nexmark_q17
 from repro.data.synthetic import (
     StreamSpec,
     airline_stream,
@@ -353,6 +355,10 @@ def _job4_feeders():
     }
 
 
+def _bid_feeders():
+    return {"bid": bid_stream(StreamSpec(rate=90.0, seed=5))}
+
+
 def _int_batches(rate=120, key_space=10_000, seed=5):
     rng = np.random.default_rng(seed)
     tick = 0
@@ -466,6 +472,10 @@ JOBS = {
     "job3": (lambda: real_job_3(keygroups_per_op=_KGS), _airline_feeders),
     "job4": (lambda: real_job_4(keygroups_per_op=_KGS), _job4_feeders),
     "pipeline": (lambda: make_pipeline_topo(_KGS), _pipeline_feeders),
+    "q17": (
+        lambda: nexmark_q17(keygroups_per_op=_KGS, expected_rows=0),
+        _bid_feeders,
+    ),
 }
 
 

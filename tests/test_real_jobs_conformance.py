@@ -30,9 +30,10 @@ SCENARIOS = {
 
 
 # Jobs with fn_jit-ported operators (job4 extends job3, so it inherits the
-# ported flight-delay operators): the +jit configuration must actually
-# exercise the compiled tier there (and never anywhere else).
-JIT_JOBS = {"job2", "job3", "job4", "pipeline"}
+# ported flight-delay operators; q17 is NEXmark's, on a seven-column
+# table): the +jit configuration must actually exercise the compiled tier
+# there (and never anywhere else).
+JIT_JOBS = {"job2", "job3", "job4", "pipeline", "q17"}
 
 
 @pytest.mark.parametrize("scenario", list(SCENARIOS), ids=str)
@@ -105,3 +106,41 @@ def test_normalize_pins_dict_insertion_order():
 
     assert normalize({"a": 1, "b": 2}) != normalize({"b": 2, "a": 1})
     assert normalize({"a": np.int64(1)}) == normalize({"a": 1})
+
+
+def test_q17_under_albic_migrations():
+    """q17 under the controller with ALBIC moving key groups off a loaded
+    node: the jit tier's rows and tables equal the per-run oracle's, bit
+    for bit, through every migration."""
+    from repro.core import AdaptationFramework, AlbicParams
+    from repro.data.nexmark import bid_stream, nexmark_q17
+    from repro.data.synthetic import StreamSpec
+    from repro.engine import Controller, ControllerConfig, Engine, ExecutionConfig
+
+    results = []
+    for config in (ExecutionConfig.oracle(), ExecutionConfig.jit()):
+        topo = nexmark_q17(keygroups_per_op=12, expected_rows=0)
+        alloc = np.zeros(topo.num_keygroups, dtype=np.int64)  # all on node 0
+        eng = Engine(topo, 4, config=config, initial_alloc=alloc,
+                     service_rate=1e9, seed=0, collect_sinks=True)
+        stream = bid_stream(StreamSpec(rate=200.0, seed=8), midnight_bid=900)
+        framework = AdaptationFramework(
+            mode="albic", max_migrations=4,
+            albic_params=AlbicParams(max_ld=10.0, time_limit=0.5),
+        )
+        ctrl = Controller(
+            eng, framework, ControllerConfig(ticks_per_period=3, warmup_periods=0),
+            feeder=lambda engine, tick: engine.push_source("bid", *next(stream)),
+        )
+        migrations = sum(ctrl.period(adapt=True).num_migrations for _ in range(4))
+        for _ in range(4):
+            eng.tick()
+        eng.end_period()
+        results.append((migrations, eng.metrics.sink_outputs,
+                        [list(s.items()) for _, s in eng.store.items()],
+                        eng.router.table.tolist(), eng.metrics.jit_calls))
+    (m0, out0, st0, al0, _), (m1, out1, st1, al1, calls) = results
+    assert m0 > 0 and calls > 0
+    assert (m1, al1) == (m0, al0)
+    assert out1 == out0
+    assert st1 == st0

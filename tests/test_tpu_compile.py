@@ -120,19 +120,48 @@ def test_keyed_running_sum_compiles_one_bucket(one_chip, monkeypatch, cap):
     s = functools.partial(_spec, one_chip)
     table = jx.TableState(
         s((cap,), jnp.int64),
-        s((cap,), jnp.float64),
+        (s((cap,), jnp.float64),),
         s((cap,), jnp.int64),
         s((cap,), jnp.int32),
         s((cap,), jnp.int32),
         s((), jnp.int32),
         s((), jnp.int64),
     )
-    lowered = jax.jit(jx.keyed_running_sum).lower(
+    lowered = jax.jit(jx.keyed_running_agg, static_argnames="combines").lower(
         table,
         s((EVENTS,), jnp.int64),
         s((EVENTS,), jnp.int64),
-        s((EVENTS,), jnp.float64),
+        (s((EVENTS,), jnp.float64),),
         s((EVENTS,), jnp.bool_),
+        combines=("sum",),
+    )
+    assert _holds_kernel(lowered)
+
+
+def test_q17_call_compiles_at_the_largest_capacity(one_chip, monkeypatch):
+    """NEXmark q17's whole jitted call — seven int64 aggregate columns, one
+    radix sort — at the q17.steady cell's sizes: 65,536 bids in 400 key
+    groups against the 1,048,576-slot table its window grows into."""
+    from repro.data import nexmark
+    from repro.engine import jitexec as jx
+    from repro.kernels.radix_sort import ops as radix_ops
+
+    monkeypatch.setattr(radix_ops.jax, "default_backend", lambda: "tpu")
+    s = functools.partial(_spec, one_chip)
+    cap, runs = 1 << 20, 512
+    col = s((cap,), jnp.int64)
+    table = jx.TableState(
+        col, (col,) * 7, col, s((cap,), jnp.int32), s((cap,), jnp.int32),
+        s((), jnp.int32), s((), jnp.int64),
+    )
+    bids = {name: s((EVENTS,), jnp.int64) for name in nexmark.BID_SCHEMA.names}
+    run = s((runs,), jnp.int64)
+    call = jx._jitted_plain(nexmark._q17_jit).__wrapped__
+    # A fresh function object: jit's trace cache is keyed by the function,
+    # and this trace must be for the described chip.
+    lowered = jax.jit(lambda *a: call(*a)).lower(
+        {"q17": table}, run, run, run, s((EVENTS,), jnp.int64), bids,
+        s((EVENTS,), jnp.float64),
     )
     assert _holds_kernel(lowered)
 

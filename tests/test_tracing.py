@@ -130,9 +130,9 @@ def test_device_fetches_of_one_sumdelay_call():
     entry count (1), the output keys and times (2) and the two value
     columns, plane and sum (2); a second call on the same key groups reads
     only its results (5); a call whose new keys overflow the table reads
-    its count (1), the codes to grow the table on the host (1), then the
-    re-run's results (5); a table holding more than half its slots is grown
-    before the next call (1)."""
+    its count (1), the table's five slab leaves to grow it on the host (5),
+    then the re-run's results (5); a table holding more than half its slots
+    is grown before the next call (5)."""
     from repro.engine.jitexec import JitRuntime
 
     topo = real_job_2(keygroups_per_op=4)
@@ -158,11 +158,11 @@ def test_device_fetches_of_one_sumdelay_call():
     assert call(64) == 5
     assert metrics.table_grows == 0
     assert (metrics.table_entries, metrics.table_capacity) == (24, 64)
-    assert call(100, planes=100) == 1 + 1 + 5
+    assert call(100, planes=100) == 1 + 5 + 5
     assert (metrics.table_grows, metrics.table_retries) == (1, 1)
     assert (metrics.table_entries, metrics.table_capacity) == (100, 256)
     assert call(150, planes=150) == 5
-    assert call(8) == 1 + 5
+    assert call(8) == 5 + 5
     assert (metrics.table_grows, metrics.table_retries) == (2, 1)
     assert (metrics.table_entries, metrics.table_capacity) == (150, 512)
 
@@ -180,3 +180,43 @@ def test_device_fetches_of_one_routed_batch():
     eng.push_source("airline", *_airline(64, 1))
     assert eng.metrics.device_fetches == 2 + eng._argsort_fetches
     assert eng.metrics.jit_host_syncs == 0
+
+
+def test_a_retry_is_its_own_span_and_capacity_costs_are_counted(tmp_path):
+    """A call whose new keys overflow its table relaunches inside a
+    ``jit.retry`` span; ``table_grow_s`` takes the grow, the relaunch and
+    the first launch at a new capacity, and each period reports its own
+    share of it."""
+    import jax
+
+    from repro.core import AdaptationFramework
+    from repro.engine import Controller, ControllerConfig
+    from repro.engine.tracing import EXTRA_SPANS
+
+    eng = Engine(
+        real_job_2(keygroups_per_op=4), 2, service_rate=1e9, seed=0,
+        config=ExecutionConfig.jit(),
+    )
+    sizes = iter([40, 300, 300, 20, 20, 20])
+
+    def feed(engine, tick):  # every airplane new: the tables overflow
+        n = next(sizes)
+        _, values, ts = _airline(n, tick)
+        values["plane"] = 1000 * tick + np.arange(n)
+        engine.push_source("airline", values["plane"].copy(), values, ts)
+
+    ctrl = Controller(eng, AdaptationFramework(mode="albic"),
+                      ControllerConfig(ticks_per_period=3), feeder=feed)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        periods = [ctrl.period(adapt=False) for _ in range(2)]
+    finally:
+        jax.profiler.stop_trace()
+    m = eng.metrics
+    assert m.table_retries >= 1
+    assert set(EXTRA_SPANS) <= _names_in_trace(str(tmp_path))
+    assert m.table_grow_s > 0
+    assert sum(p.table_grow_s for p in periods) == m.table_grow_s
+    assert periods[1].table_grow_s > 0
