@@ -49,6 +49,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import itertools
+import math
 import time
 import weakref
 from typing import NamedTuple, Optional
@@ -109,7 +110,8 @@ class TableState(NamedTuple):
     without ranking new entries per key group — and ``perm`` is the code-sorted permutation of the slab, maintained
     *incrementally* by the merge in :func:`keyed_running_agg` (new codes
     arrive pre-sorted from the segment sort, so keeping the view sorted
-    costs searchsorted + prefix sums, never a table-sized sort).
+    costs one int32 scatter of marks, a prefix count and one gather, never
+    a table-sized sort).
     """
 
     codes: jax.Array  # (S,) int64, insertion order
@@ -201,6 +203,48 @@ def segmented_scan(xs: tuple, head: jax.Array, combines: tuple) -> tuple:
     return jax.lax.associative_scan(combine, (head, tuple(xs)))[1]
 
 
+def _prefix(x: jax.Array, combine: str) -> jax.Array:
+    """Inclusive prefix sums (``"sum"``) or maxima (``"max"``, of
+    non-negative values) of the 1-D int32 ``x``, in two levels of at most
+    1,024: XLA:TPU compiles one window reduction over 1,048,576 elements
+    for about 22 s, two levels in under one."""
+    n = x.shape[0]
+    b = math.gcd(n, 1024)
+    scan = jax.lax.cumsum if combine == "sum" else jax.lax.cummax
+    inner = scan(x.reshape(n // b, b), axis=1)
+    carry = scan(inner[:, -1])
+    carry = jnp.concatenate([jnp.zeros(1, x.dtype), carry[:-1]])
+    op = jnp.add if combine == "sum" else jnp.maximum
+    return op(inner, carry[:, None]).reshape(n)
+
+
+def _wide(dtype):
+    """The 32-bit dtype a narrower column rides in: exact both ways."""
+    return jnp.float32 if jnp.issubdtype(dtype, jnp.floating) else jnp.int32
+
+
+def _words(x: jax.Array) -> list:
+    """A column as uint32 words: one for up to 32 bits (a narrower column
+    widened first), two (low, high) for 64."""
+    if x.dtype.itemsize < 4:
+        x = x.astype(_wide(x.dtype))
+    if x.dtype.itemsize == 4:
+        return [jax.lax.bitcast_convert_type(x, jnp.uint32)]
+    u = jax.lax.bitcast_convert_type(x, jnp.uint64)
+    return [u.astype(jnp.uint32), (u >> jnp.uint64(32)).astype(jnp.uint32)]
+
+
+def _unwords(words, dtype) -> jax.Array:
+    """The inverse of :func:`_words`, from one or two rows of words."""
+    dtype = jnp.dtype(dtype)
+    if len(words) == 1 and dtype.itemsize < 4:
+        return jax.lax.bitcast_convert_type(words[0], _wide(dtype)).astype(dtype)
+    if len(words) == 1:
+        return jax.lax.bitcast_convert_type(words[0], dtype)
+    lo, hi = (w.astype(jnp.uint64) for w in words)
+    return jax.lax.bitcast_convert_type(lo | (hi << jnp.uint64(32)), dtype)
+
+
 def keyed_running_agg(
     table: TableState,
     codes: jax.Array,
@@ -225,10 +269,19 @@ def keyed_running_agg(
 
     Cost, whatever the number of columns: ONE stable sort of the segment
     (:func:`radix_argsort_jax`), ONE lookup through the table's code-sorted
-    view, and ONE merge of the segment's pre-sorted new codes into that
-    view (searchsorted + prefix sums, never a table-sized sort); per column
-    one gather of the table values, one :func:`segmented_scan` lane and two
-    scatters.  A ``"sum"`` column's group is seeded ``base + first term``
+    view, ONE :func:`segmented_scan` and THREE scatters, all of int32 words
+    (on a TPU a 64-bit scatter costs 13 to 19 times an int32 one, and a
+    gather of one element per slot more than either at large capacities):
+    ``inv``, the segment's sorted → tuple order map, which each running
+    column then gathers through; the slab writes, every written column
+    (codes, values, ``seq``, ``owner``) as rows of uint32 words of one
+    (words, capacity) array, a 64-bit column as two rows; and the merge
+    of the segment's pre-sorted new codes into the sorted view, their slab
+    indices marked at their merged positions, an int32 prefix count of the
+    marks and one gather of the old view (never a table-sized sort or
+    search).  A new entry's first position comes from an int32 prefix
+    maximum over the group heads.
+    A ``"sum"`` column's group is seeded ``base + first term``
     (``0 + term`` for a new key, as the oracle's ``get(key, 0) + term``);
     a ``"min"``/``"max"`` column's only where the key is in the table.
     Float sums are the one place the jit tier may diverge from the
@@ -252,17 +305,20 @@ def keyed_running_agg(
     """
     nb = codes.shape[0]
     cap = table.codes.shape[0]
+    i32 = jnp.int32
     mcodes = jnp.where(valid, codes, EMPTY_CODE)
     if order is None:
         order = radix_argsort_jax(mcodes)  # stable: ties keep tuple order
+    order = order.astype(i32)
     sc = mcodes[order]
     real = sc != EMPTY_CODE
     sk = jnp.where(real, kg[order], 0)
     head = jnp.concatenate([jnp.ones(1, bool), sc[1:] != sc[:-1]])
     # Lookup through the maintained code-sorted view.
     scodes = table.codes[table.perm]  # (cap,) sorted, EMPTY tail
-    pos = jnp.minimum(jnp.searchsorted(scodes, sc), cap - 1)
-    fidx = table.perm[pos].astype(jnp.int64)  # candidate slab index
+    ins = jnp.searchsorted(scodes, sc).astype(i32)  # merge position
+    pos = jnp.minimum(ins, cap - 1)
+    fidx = table.perm[pos]  # candidate slab index
     has = (scodes[pos] == sc) & real
     # Within-group running values, each group seeded with its table value.
     seeded = []
@@ -274,68 +330,63 @@ def keyed_running_agg(
         else:
             seeded.append(jnp.where(head & has, _COMBINE[c](base, sa), sa))
     running_sorted = segmented_scan(seeded, head, combines)
-    running = tuple(
-        jnp.zeros(nb, r.dtype).at[order].set(r) for r in running_sorted
-    )
-    # Integer prefix counts run in int32 (they are bounded by the segment
-    # and table sizes): XLA:TPU compiles int64 scans several times slower.
-    seg = jnp.cumsum(head, dtype=jnp.int32) - 1  # group per sorted position
+    iota = jnp.arange(nb, dtype=i32)
+    inv = jnp.zeros(nb, i32).at[order].set(iota)  # tuple → sorted position
+    running = tuple(r[inv] for r in running_sorted)
     # ---- table update ----------------------------------------------------
+    headpos = _prefix(jnp.where(head, iota, 0), "max")  # each group's head
     tail = jnp.concatenate([head[1:], jnp.ones(1, bool)])
     newhead = head & real & ~has
-    nc_in = jnp.cumsum(newhead, dtype=jnp.int32).astype(jnp.int64)  # new count
+    nc_in = _prefix(newhead.astype(i32), "sum")  # new codes up to here
     total_new = nc_in[-1]
     rank = nc_in - 1  # code-order rank among new codes (valid at newheads)
-    dest = table.cnt.astype(jnp.int64) + rank  # slab append position
-    # Slab index per group (existing: the hit; new: the append slot),
-    # broadcast from heads to the whole group.
-    slab_head = jnp.where(has, fidx, dest)
-    slabarr = (
-        jnp.zeros(nb, slab_head.dtype)
-        .at[jnp.where(head, seg, nb)]
-        .set(jnp.where(head, slab_head, 0), mode="drop")
+    dest = table.cnt.astype(i32) + rank  # slab append position
+    # Slab index of each group (existing: the hit; new: the append slot),
+    # the same at each of its positions, since only a head counts a new
+    # code; a group's tail writes it.  Other positions point beyond the
+    # capacity, and drop.
+    widx = jnp.where(tail & real, jnp.where(has, fidx, dest), cap)
+    # seq/owner change only for new entries: a hit rewrites its own.
+    seq_new = (table.epoch << jnp.int64(32)) | order[headpos]
+    old = (table.codes, *table.vals, table.seq, table.owner)
+    upd = (sc, *running_sorted, jnp.where(has, table.seq[fidx], seq_new),
+           jnp.where(has, table.owner[fidx], sk))
+    # float64 columns, whose bits XLA:TPU cannot read, gather instead
+    # through each slot's writer: its sorted position + 1 in the last row.
+    words = [[] if c.dtype == jnp.float64 else _words(c) for c in old]
+    slab = jnp.stack(
+        [w for ws in words for w in ws] + [jnp.zeros(cap, jnp.uint32)]
     )
-    widx_all = slabarr[seg]
-    wvalid = tail & real
-    widx = jnp.where(wvalid, widx_all, cap)  # out of range → dropped
-    codes2 = table.codes.at[widx].set(sc, mode="drop")
-    vals2 = tuple(
-        col.at[widx].set(r, mode="drop")
-        for col, r in zip(table.vals, running_sorted)
+    rows = [w for c, x, ws in zip(old, upd, words) if ws
+            for w in _words(x.astype(c.dtype))]
+    slab = slab.at[:, widx].set(
+        jnp.stack(rows + [(iota + 1).astype(jnp.uint32)]), mode="drop"
     )
-    # seq/owner only change for new entries (scatter at newheads).
-    nidx = jnp.where(newhead, dest, cap)
-    seq_new = (table.epoch << jnp.int64(32)) | order
-    seq2 = table.seq.at[nidx].set(seq_new, mode="drop")
-    owner2 = table.owner.at[nidx].set(sk.astype(jnp.int32), mode="drop")
-    # Merge the pre-sorted new codes into the sorted view.  Invariant: the
-    # EMPTY tail of ``perm`` is ascending by slab index, so the entries the
+    src = slab[-1].astype(i32) - 1  # -1: the slot was not written
+    cols = []
+    for col, x, ws in zip(old, upd, words):
+        if ws:
+            cols.append(_unwords(slab[:len(ws)], col.dtype))
+            slab = slab[len(ws):]
+        else:
+            cols.append(jnp.where(src >= 0, x[jnp.maximum(src, 0)], col))
+    codes2, *vals2, seq2, owner2 = cols
+    # Merge the pre-sorted new codes into the sorted view: each new code's
+    # slab index at its merged position; an unmarked position p below the
+    # new count takes the old view's entry p − (marks up to p); beyond it
+    # the EMPTY tail stays, ascending by slab index, so the entries the
     # append consumes are exactly the FIRST ``total_new`` EMPTY pointers.
-    ncex = jnp.concatenate([jnp.zeros(1, jnp.int64), nc_in])  # exclusive
-    is_empty_old = scodes == EMPTY_CODE
-    shift_real = ncex[jnp.searchsorted(sc, scodes, side="left")]
-    arange_cap = jnp.arange(cap)
-    # The EMPTY entries are the view's tail, after its ``cnt`` real codes:
-    # their rank among the EMPTY pointers is their position less ``cnt``.
-    jemp = arange_cap - table.cnt
-    oldpos = jnp.where(
-        is_empty_old,
-        jnp.where(jemp < total_new, cap, arange_cap),  # consumed → dropped
-        arange_cap + shift_real,
-    )
-    perm2 = (
-        jnp.zeros(cap, table.perm.dtype)
-        .at[oldpos]
-        .set(table.perm, mode="drop")
-    )
-    npos = jnp.where(
-        newhead, jnp.searchsorted(scodes, sc, side="left") + rank, cap
-    )
-    perm2 = perm2.at[npos].set(dest.astype(table.perm.dtype), mode="drop")
+    npos = jnp.where(newhead, ins + rank, cap)
+    mark = jnp.full(cap, -1, i32).at[npos].set(dest, mode="drop")
+    marked = mark >= 0
+    p = jnp.arange(cap, dtype=i32)
+    below = p < table.cnt.astype(i32) + total_new
+    shift = jnp.where(below, _prefix(marked.astype(i32), "sum"), 0)
+    perm2 = jnp.where(marked, mark, table.perm[p - shift])
     return (
         TableState(
             codes2,
-            vals2,
+            tuple(vals2),
             seq2,
             owner2,
             perm2,

@@ -644,38 +644,134 @@ def _agg_table(cap):
     return table._replace(vals=tuple(vals))
 
 
-def test_keyed_running_agg_matches_a_numpy_loop():
+def _hot_and_new(rng, call, nb):
+    codes = rng.integers(0, 40 + 60 * call, size=nb)
+    codes[rng.random(nb) < 0.5] = 7  # the hot key: long segments
+    return codes, rng.integers(1, 200, size=nb), nb - 12
+
+
+#: Edge cases of the table update, one scenario each: (capacity, tuples a
+#: call, one function per call giving codes, prices and the valid count).
+AGG_CASES = {
+    # A hot key holding half of each segment, new keys in each call.
+    "hot_key_and_new_keys": (1024, 512, [_hot_and_new] * 3),
+    # 40 keys, then 24 new ones beside hits: the table ends exactly full.
+    "fills_to_capacity": (64, 192, [
+        lambda rng, c, nb: (np.tile(rng.permutation(np.arange(100, 140)), 5)[:nb],
+                            rng.integers(1, 200, size=nb), 160),
+        lambda rng, c, nb: (np.concatenate([np.arange(140, 164), np.tile(
+            np.arange(100, 140), 5)])[:nb], rng.integers(1, 200, size=nb), 192),
+    ]),
+    # 40 keys, then 30 new ones: the call overflows the 64 slots.
+    "overflows": (64, 128, [
+        lambda rng, c, nb: (np.tile(np.arange(100, 140), 4)[:nb],
+                            rng.integers(1, 200, size=nb), 120),
+        lambda rng, c, nb: (np.concatenate([np.arange(200, 230), np.tile(
+            np.arange(100, 140), 3)])[:nb], rng.integers(1, 200, size=nb), 128),
+    ]),
+    # The second call hits existing keys only.
+    "only_hits": (128, 256, [
+        lambda rng, c, nb: (rng.integers(0, 50, size=nb),
+                            rng.integers(1, 200, size=nb), 250),
+        lambda rng, c, nb: (rng.integers(0, 50, size=nb),
+                            rng.integers(1, 200, size=nb), 256),
+    ]),
+    # Every call brings keys the table has never held.
+    "only_new_keys": (256, 128, [
+        lambda rng, c, nb: (rng.integers(50 * c, 50 * c + 50, size=nb),
+                            rng.integers(1, 200, size=nb), 120),
+    ] * 3),
+    # A call of padding alone changes nothing but the epoch.
+    "padding_only": (128, 128, [
+        lambda rng, c, nb: (rng.integers(0, 60, size=nb),
+                            rng.integers(1, 200, size=nb), 100),
+        lambda rng, c, nb: (rng.integers(0, 60, size=nb),
+                            rng.integers(1, 200, size=nb), 0),
+    ]),
+    # New codes below and above every code in the table, beside hits.
+    "new_codes_below_and_above": (256, 256, [
+        lambda rng, c, nb: (rng.integers(1000, 1050, size=nb),
+                            rng.integers(1, 200, size=nb), 256),
+        lambda rng, c, nb: (rng.choice(np.concatenate([
+            np.arange(0, 10), np.arange(1000, 1050), np.arange(5000, 5010)]),
+            size=nb), rng.integers(1, 200, size=nb), 240),
+    ]),
+    # Negative prices: min and max below zero, negative sums.
+    "negative_under_min_max": (256, 256, [
+        lambda rng, c, nb: (rng.integers(0, 30 + 30 * c, size=nb),
+                            rng.integers(-500, 0, size=nb), 250),
+    ] * 3),
+}
+
+
+def _check_view(table):
+    """The code-sorted view: a permutation of the slots, real codes in
+    ascending order, then the EMPTY slots ascending by slab index."""
+    from repro.engine import jitexec as jx
+
+    perm = np.asarray(table.perm)
+    codes = np.asarray(table.codes)
+    cnt = int(table.cnt)
+    assert sorted(perm.tolist()) == list(range(len(perm)))
+    assert np.all(np.diff(codes[perm[:cnt]]) > 0)
+    assert np.all(codes[perm[cnt:]] == jx.EMPTY_CODE)
+    assert perm[cnt:].tolist() == list(range(cnt, len(perm)))
+
+
+@pytest.mark.parametrize("case", list(AGG_CASES))
+def test_keyed_running_agg_matches_a_numpy_loop(case):
     """Four columns under sum (a count-if and a float sum), min and max
-    against a python fold: a hot key holding half of each segment, new keys
-    in each call, and the table's insertion order and sorted view."""
+    against a python fold, call by call, in each edge case of
+    ``AGG_CASES``: the running values, the table's entries in insertion
+    order, and its sorted view.  A call whose new keys overflow the table
+    leaves it held as it came in, its count reading the keys it needed."""
+    import copy
+
     import jax
     import jax.numpy as jnp
 
     from repro.engine import jitexec as jx
 
+    cap, nb, calls = AGG_CASES[case]
     rng = np.random.default_rng(3)
-    nb, n, num_kg = 512, 500, 4
+    num_kg = 4
     dicts = [dict() for _ in range(num_kg)]
-    table = _agg_table(1024)
+    table = _agg_table(cap)
     agg = jax.jit(jx.keyed_running_agg, static_argnames="combines")
-    for call in range(3):
-        codes = rng.integers(0, 40 + 60 * call, size=nb).astype(np.int64)
-        codes[rng.random(nb) < 0.5] = 7  # the hot key: long segments
+    for call, make in enumerate(calls):
+        codes, prices, n = make(rng, call, nb)
+        codes = np.asarray(codes, np.int64)
+        prices = np.asarray(prices, np.int64)
         kg = codes % num_kg
-        prices = rng.integers(1, 200, size=nb).astype(np.int64)
         valid = np.arange(nb) < n
-        table, running = agg(
+        new, running = agg(
             table, jnp.asarray(codes), jnp.asarray(kg),
             tuple(jnp.asarray(t) for t in _agg_terms(prices)),
             jnp.asarray(valid), combines=AGG,
         )
-        expect = _agg_loop(dicts, codes[:n], kg[:n], prices[:n])
+        after = copy.deepcopy(dicts)
+        expect = _agg_loop(after, codes[:n], kg[:n], prices[:n])
+        entries = sum(len(d) for d in after)
+        assert int(new.cnt) == entries
+        if entries > cap:
+            held = jx._hold_on_overflow({"t": table}, {"t": new})["t"]
+            assert int(held.cnt) == entries
+            for a, b in zip(jax.tree_util.tree_leaves(held._replace(cnt=table.cnt)),
+                            jax.tree_util.tree_leaves(table)):
+                assert np.array_equal(np.asarray(a), np.asarray(b))
+            continue
+        if n == 0:  # padding alone: every leaf but the epoch as it was
+            for a, b in zip(new[:-1], table[:-1]):
+                for x, y in zip(jax.tree_util.tree_leaves(a),
+                                jax.tree_util.tree_leaves(b)):
+                    assert np.array_equal(np.asarray(x), np.asarray(y))
+        dicts, table = after, new
         got = [np.asarray(r)[:n] for r in running]
         for j in (0, 2, 3):  # integer columns: exact
             assert got[j].tolist() == [row[j] for row in expect]
         np.testing.assert_allclose(got[1], [row[1] for row in expect], rtol=1e-12)
+        assert int(table.epoch) == call + 2
         cnt = int(table.cnt)
-        assert cnt == sum(len(d) for d in dicts)
         t_codes = np.asarray(table.codes)[:cnt]
         t_seq = np.asarray(table.seq)[:cnt]
         t_owner = np.asarray(table.owner)[:cnt]
@@ -686,9 +782,24 @@ def test_keyed_running_agg_matches_a_numpy_loop():
             for j in (0, 2, 3):
                 col = np.asarray(table.vals[j])[order]
                 assert col.tolist() == [row[j] for row in dicts[k].values()]
-        perm = np.asarray(table.perm)
-        assert sorted(perm.tolist()) == list(range(len(perm)))
-        assert np.all(np.diff(np.asarray(table.codes)[perm]) >= 0)
+        _check_view(table)
+    assert case != "overflows" or int(table.cnt) == 40
+    assert case != "fills_to_capacity" or int(table.cnt) == cap
+
+
+@pytest.mark.parametrize("combine", ["sum", "max"])
+@pytest.mark.parametrize("n", [1, 6, 1024, 3072, 8192])
+def test_prefix_in_two_levels_matches_numpy(n, combine):
+    """The table update's int32 prefix sums and maxima, in blocks of up to
+    1,024 and a carry between blocks, against numpy's one pass."""
+    import jax.numpy as jnp
+
+    from repro.engine import jitexec as jx
+
+    x = np.random.default_rng(n).integers(0, 1000, size=n).astype(np.int32)
+    got = np.asarray(jx._prefix(jnp.asarray(x), combine))
+    want = np.cumsum(x) if combine == "sum" else np.maximum.accumulate(x)
+    assert got.dtype == np.int32 and got.tolist() == want.tolist()
 
 
 @pytest.mark.parametrize("columns", [1, 7])
@@ -713,6 +824,83 @@ def test_one_sort_per_call_whatever_the_columns(columns):
 
     hlo = jax.jit(call).lower(table, x, x % 4, x < 100).as_text()
     assert hlo.count("stablehlo.sort") == 1, hlo.count("stablehlo.sort")
+
+
+@pytest.mark.parametrize("columns,dtype", [
+    (1, np.int64), (7, np.int64), (1, np.float64), (3, np.int16),
+])
+def test_no_64_bit_scatter_and_three_scatters_per_call(columns, dtype):
+    """The lowered program of a call scatters no 64-bit operand, indices
+    or updates, and holds at most three scatters (the radix sort's own
+    lie on the TPU's path alone), for one column as for q17's seven, and
+    for float64 and 16-bit columns: the values move through int32 index
+    maps and rows of 32-bit words."""
+    import re
+
+    import jax
+    from repro.engine import jitexec as jx
+    from repro.engine.topology import StateField
+
+    combine = "sum" if columns == 1 else ("sum",) * (columns - 3) + (
+        "min", "max", "sum")
+    f = StateField("t", "table", dtype=dtype, key_encode=int,
+                   key_decode=int, combine=combine)
+    table = jx._empty_table(256, f)
+    nb = 128
+    x = np.arange(nb, dtype=np.int64)
+    terms = (x.astype(dtype),) * columns
+
+    def call(table, codes, kg, terms, valid):
+        return jx.keyed_running_agg(table, codes, kg, terms, valid, f.combines)
+
+    hlo = jax.jit(call).lower(table, x, x % 4, terms, x < 100).as_text()
+    types = re.findall(
+        r'"stablehlo\.scatter"\(.*?\}\) : \(([^)]*)\) ->', hlo, re.DOTALL
+    )
+    assert hlo.count('"stablehlo.scatter"(') == len(types)
+    assert 1 <= len(types) <= 3, len(types)
+    for sig in types:
+        assert not re.search(r"[x<](?:ui|i|f)64>", sig), sig
+
+
+@pytest.mark.parametrize("dtype", [np.int8, np.int16, np.float16, "bfloat16"])
+def test_narrow_columns_match_a_numpy_fold(dtype):
+    """Columns of 8 and 16 bits under sum, min and max, whose slab writes
+    ride widened to 32-bit words, against a fold in the column's dtype:
+    small values, so every float sum is exact whatever its order."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.engine import jitexec as jx
+    from repro.engine.topology import StateField
+
+    dtype = jnp.dtype(dtype)
+    combines = ("sum", "min", "max")
+    f = StateField("t", "table", dtype=dtype, key_encode=int,
+                   key_decode=int, combine=combines)
+    table = jx._empty_table(256, f)
+    agg = jax.jit(jx.keyed_running_agg, static_argnames="combines")
+    rng = np.random.default_rng(5)
+    rows, nb = {}, 128
+    for call in range(3):
+        codes = rng.integers(0, 20 + 20 * call, size=nb).astype(np.int64)
+        terms = rng.integers(-8, 9, size=nb)
+        table, running = agg(
+            table, jnp.asarray(codes), jnp.asarray(codes % 4),
+            (jnp.asarray(terms, dtype),) * 3, jnp.asarray(np.ones(nb, bool)),
+            combines=combines,
+        )
+        assert all(r.dtype == dtype for r in running + table.vals)
+        got = np.stack([np.asarray(r, np.float64) for r in running], 1)
+        for i, (c, t) in enumerate(zip(codes.tolist(), terms.tolist())):
+            s, lo, hi = rows.get(c, (0, t, t))
+            rows[c] = (s + t, min(lo, t), max(hi, t))
+            assert got[i].tolist() == list(rows[c])
+        cnt = int(table.cnt)
+        vals = np.stack([np.asarray(v, np.float64)[:cnt] for v in table.vals], 1)
+        stored = dict(zip(np.asarray(table.codes)[:cnt].tolist(), vals.tolist()))
+        assert stored == {c: list(r) for c, r in rows.items()}
+        _check_view(table)
 
 
 #: sha256 of a one-column table's running sums and leaves after two Job 2
